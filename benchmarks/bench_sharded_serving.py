@@ -26,8 +26,10 @@ absorb, and why the gate is on the calibrated path.
 
 XLA fixes the device count at backend init, and benchmarks/run.py runs many
 suites in one process that must see the host as-is — so when this process
-has fewer than 2 devices the measurement re-executes itself in a subprocess
-with ``--xla_force_host_platform_device_count=8`` and adopts its rows.
+has fewer than 2 CPU devices the measurement re-executes itself in a
+CPU-only subprocess with ``--xla_force_host_platform_device_count=8`` and
+adopts its rows.  An accelerator belongs to the one process that opened
+it, so on a single chip the suite fails instead of starting that child.
 """
 from __future__ import annotations
 
@@ -192,7 +194,7 @@ def _measure(rounds: int = 30, d: int = 192):
 
 def _measure_subprocess(rounds: int):
     """Re-exec with forged devices; adopt the child's rows."""
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     flags = env.get("XLA_FLAGS", "")
     env["XLA_FLAGS"] = (flags +
                         f" --xla_force_host_platform_device_count={N_DEVICES}"
@@ -217,8 +219,13 @@ def run(rounds: int = 30):
     import jax
     if len(jax.devices()) >= 2:
         rows = _measure(rounds)
-    else:
+    elif jax.default_backend() == "cpu":
         rows = _measure_subprocess(rounds)
+    else:
+        raise RuntimeError(
+            f"sharded serving needs >= 2 devices; this process holds one "
+            f"{jax.default_backend()} device, and a child process cannot "
+            f"open the chip its parent holds")
     gate_row = None
     for r in rows:
         fields = {k: v for k, v in r.items()

@@ -40,6 +40,8 @@ BENCH_JSON = os.path.join(os.path.dirname(__file__), "..", "BENCH_PR10.json")
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (bench_compression, bench_failover, bench_kernels,
                    bench_model_serving, bench_netfault, bench_pp_serving,
                    bench_pubsub, bench_qos, bench_query,

@@ -343,7 +343,6 @@ class ExecutionPlan:
         :meth:`shardable_batch` holds — state has no leaves, hence no carry
         crosses the shard boundary."""
         from jax.sharding import PartitionSpec as P
-        from ..jaxcompat import shard_map
         from ..launch.mesh import batch_spec, data_axis_size
         dspec = P(batch_spec(mesh))
         n_local = (jax.tree_util.tree_leaves(inputs)[0].shape[0]
@@ -366,9 +365,9 @@ class ExecutionPlan:
             _, outs = lax.scan(body, s, local)
             return outs
 
-        outs = shard_map(local_scan, mesh=mesh,
-                         in_specs=(P(), P(), dspec),
-                         out_specs=dspec)(params, state, inputs)
+        outs = jax.shard_map(local_scan, mesh=mesh,
+                             in_specs=(P(), P(), dspec),
+                             out_specs=dspec)(params, state, inputs)
         # no state leaves: the scan carry is pure structure, returned as-is
         return outs, dict(state)
 

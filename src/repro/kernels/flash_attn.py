@@ -64,6 +64,18 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
+def flash_blocks(s: int):
+    """-> (padded length, block) for a causal flash call over ``s``
+    positions.  Up to 512 one block spans the sequence; beyond, the length
+    is padded to a multiple of 128 and the block is the largest of 512,
+    256, 128 dividing it (600 -> 640 in blocks of 128, not 1024 in 512s).
+    Causal masking makes the padded keys inert for every real query."""
+    if s <= 512:
+        return s, s
+    sp = -(-s // 128) * 128
+    return sp, next(b for b in (512, 256, 128) if sp % b == 0)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("causal", "bq", "bk", "interpret",
                                     "kv_groups"))
@@ -75,11 +87,15 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 512,
 
     GQA: ``kv_groups`` q-heads share one kv head — handled in the BlockSpec
     index map (no broadcast materialization).  Sq/Sk must be multiples of
-    bq/bk (pad upstream)."""
+    bq/bk: pad upstream (:func:`flash_blocks`) — a remainder block would
+    read past the arrays and write NaN rows."""
     bh, sq, dk = q.shape
     _, sk, dv = v.shape
     bq = min(bq, sq)
     bk = min(bk, sk)
+    if sq % bq or sk % bk:
+        raise ValueError(f"flash_attention: sq={sq}, sk={sk} are not "
+                         f"multiples of bq={bq}, bk={bk}; pad upstream")
     scale = dk ** -0.5
     grid = (bh, sq // bq, sk // bk)
     g = kv_groups

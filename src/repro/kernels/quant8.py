@@ -6,8 +6,12 @@ paper's gst-gz/JPEG frame codecs — on TPU, bandwidth is saved by dtype
 narrowing, not byte-level entropy coding).
 
 Tiling: (32, 128) blocks — int8 native tile on TPU (sublane 32 × lane 128);
-one f32 scale per tile.  Grid = (M/32, N/128); each program reads one VMEM
-tile, computes absmax, writes the quantized tile + its scale.
+one f32 scale per tile.  The wrapper lays the tiles out as one column
+(``_tile_column``); each grid step reads ``TILES_PER_STEP`` tiles into VMEM
+and, tile by tile, computes the absmax and writes the quantized tile plus
+its scale (a scalar into an SMEM block).  A block of one tile and one
+scale would break the TPU's rule that a block's last two dimensions divide
+by (8, 128) or span the array.
 
 :func:`quantize8_xla`/:func:`dequantize8_xla` are the bitwise-identical
 vectorized XLA statements of the same per-tile contract — the fast path
@@ -21,59 +25,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .ref import QUANT_BM, QUANT_BN
-
-
-def _quant_kernel(x_ref, q_ref, s_ref):
-    x = x_ref[...].astype(jnp.float32)
-    amax = jnp.max(jnp.abs(x))
-    scale = jnp.where(amax > 0, amax / 127.0, 1.0)
-    q_ref[...] = jnp.round(x / scale).astype(jnp.int8)
-    s_ref[0, 0] = scale
-
-
-def _dequant_kernel(q_ref, s_ref, x_ref):
-    x_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[0, 0]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def quantize8_pallas(x: jnp.ndarray, *, interpret: bool = True):
-    """x: [M, N] (M % 32 == 0, N % 128 == 0) -> (q int8 [M,N], scales [M/32, N/128])."""
-    m, n = x.shape
-    gm, gn = m // QUANT_BM, n // QUANT_BN
-    return pl.pallas_call(
-        _quant_kernel,
-        grid=(gm, gn),
-        in_specs=[pl.BlockSpec((QUANT_BM, QUANT_BN), lambda i, j: (i, j))],
-        out_specs=[
-            pl.BlockSpec((QUANT_BM, QUANT_BN), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m, n), jnp.int8),
-            jax.ShapeDtypeStruct((gm, gn), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def dequantize8_pallas(q: jnp.ndarray, scales: jnp.ndarray, *,
-                       interpret: bool = True):
-    m, n = q.shape
-    gm, gn = scales.shape
-    return pl.pallas_call(
-        _dequant_kernel,
-        grid=(gm, gn),
-        in_specs=[
-            pl.BlockSpec((QUANT_BM, QUANT_BN), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        ],
-        out_specs=[pl.BlockSpec((QUANT_BM, QUANT_BN), lambda i, j: (i, j))],
-        out_shape=[jax.ShapeDtypeStruct((m, n), jnp.float32)],
-        interpret=interpret,
-    )(q, scales)[0]
 
 
 def _as_tiles(x: jnp.ndarray):
@@ -86,6 +40,94 @@ def _as_tiles(x: jnp.ndarray):
 def _from_tiles(t: jnp.ndarray):
     gm, gn, bm, bn = t.shape
     return t.transpose(0, 2, 1, 3).reshape(gm * bm, gn * bn)
+
+
+#: tiles per grid step (the wrapper pads the tile count up to a multiple)
+TILES_PER_STEP = 64
+
+
+def _quant_kernel(x_ref, q_ref, s_ref):
+    def tile(r, carry):
+        rows = pl.ds(pl.multiple_of(r * QUANT_BM, QUANT_BM), QUANT_BM)
+        x = x_ref[rows, :].astype(jnp.float32)
+        amax = jnp.max(jnp.abs(x))
+        scale = jnp.where(amax > 0, amax / 127.0, 1.0)
+        q_ref[rows, :] = jnp.round(x / scale).astype(jnp.int8)
+        s_ref[r, 0] = scale
+        return carry
+    jax.lax.fori_loop(0, s_ref.shape[0], tile, 0)
+
+
+def _dequant_kernel(q_ref, s_ref, x_ref):
+    def tile(r, carry):
+        rows = pl.ds(pl.multiple_of(r * QUANT_BM, QUANT_BM), QUANT_BM)
+        x_ref[rows, :] = q_ref[rows, :].astype(jnp.float32) * s_ref[r, 0]
+        return carry
+    jax.lax.fori_loop(0, s_ref.shape[0], tile, 0)
+
+
+def _tile_column(x: jnp.ndarray) -> jnp.ndarray:
+    """[M, N] -> [T*BM, BN]: every (BM, BN) tile stacked in row-major tile
+    order, so one tile column carries the whole array (a no-op view when
+    N == BN).  Keeps the kernel's blocks and its per-tile scale blocks
+    inside the TPU's (8, 128) block-shape rule for any M and N."""
+    gm, gn = x.shape[0] // QUANT_BM, x.shape[1] // QUANT_BN
+    return _as_tiles(x).reshape(gm * gn * QUANT_BM, QUANT_BN)
+
+
+def _steps(n_tiles: int):
+    """-> (tiles per grid step, padded tile count)."""
+    r = min(TILES_PER_STEP, n_tiles)
+    return r, -(-n_tiles // r) * r
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def quantize8_pallas(x: jnp.ndarray, *, interpret: bool = True):
+    """x: [M, N] (M % 32 == 0, N % 128 == 0) -> (q int8 [M,N], scales [M/32, N/128])."""
+    m, n = x.shape
+    gm, gn = m // QUANT_BM, n // QUANT_BN
+    r, t = _steps(gm * gn)
+    col = jnp.pad(_tile_column(x), ((0, (t - gm * gn) * QUANT_BM), (0, 0)))
+    q, s = pl.pallas_call(
+        _quant_kernel,
+        grid=(t // r,),
+        in_specs=[pl.BlockSpec((r * QUANT_BM, QUANT_BN), lambda i: (i, 0))],
+        out_specs=[
+            pl.BlockSpec((r * QUANT_BM, QUANT_BN), lambda i: (i, 0)),
+            pl.BlockSpec((r, 1), lambda i: (i, 0), memory_space=pltpu.SMEM),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((t * QUANT_BM, QUANT_BN), jnp.int8),
+            jax.ShapeDtypeStruct((t, 1), jnp.float32),
+        ],
+        interpret=interpret,
+    )(col)
+    q = q[:gm * gn * QUANT_BM].reshape(gm, gn, QUANT_BM, QUANT_BN)
+    return _from_tiles(q), s[:gm * gn].reshape(gm, gn)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def dequantize8_pallas(q: jnp.ndarray, scales: jnp.ndarray, *,
+                       interpret: bool = True):
+    gm, gn = scales.shape
+    r, t = _steps(gm * gn)
+    pad = t - gm * gn
+    col = jnp.pad(_tile_column(q), ((0, pad * QUANT_BM), (0, 0)))
+    s = jnp.pad(scales.reshape(gm * gn, 1), ((0, pad), (0, 0)),
+                constant_values=1.0)
+    x = pl.pallas_call(
+        _dequant_kernel,
+        grid=(t // r,),
+        in_specs=[
+            pl.BlockSpec((r * QUANT_BM, QUANT_BN), lambda i: (i, 0)),
+            pl.BlockSpec((r, 1), lambda i: (i, 0), memory_space=pltpu.SMEM),
+        ],
+        out_specs=pl.BlockSpec((r * QUANT_BM, QUANT_BN), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((t * QUANT_BM, QUANT_BN), jnp.float32),
+        interpret=interpret,
+    )(col, s)
+    x = x[:gm * gn * QUANT_BM].reshape(gm, gn, QUANT_BM, QUANT_BN)
+    return _from_tiles(x)
 
 
 @jax.jit

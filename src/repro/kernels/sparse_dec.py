@@ -5,8 +5,8 @@ its KB coordinate slots.  GPU would scatter with atomics; TPU has no scatter
 in VMEM, so we again use a one-hot MXU matmul:
 
     local   = indices - block_base                 # [KB]
-    onehot  = (local[:,None] == arange(B)[None,:]) # [KB, B]
-    dense   = values @ onehot                      # MXU   [B]
+    onehotT = (arange(B)[:,None] == local[None,:]) # [B, KB]
+    dense   = values @ onehotT.T                   # MXU   [B]
 
 Empty slots carry (value=0, index=block_base): their one-hot row is real but
 the zero value contributes nothing — the "no-op scatter" trick that keeps
@@ -29,14 +29,23 @@ from jax.experimental import pallas as pl
 from .ref import SPARSE_B
 
 
+#: COO blocks per grid step (TPU block rows divide by 8 or span the array)
+BLOCKS_PER_STEP = 8
+
+
 def _dec_kernel(vals_ref, idx_ref, out_ref):
-    kb = vals_ref.shape[1]
+    g, kb = vals_ref.shape
     b = out_ref.shape[1]
-    v = vals_ref[0, :].astype(jnp.float32)                    # [KB]
-    local = idx_ref[0, :] - pl.program_id(0) * b              # [KB]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (kb, b), 1)
-    onehot = (jnp.broadcast_to(local[:, None], (kb, b)) == cols).astype(jnp.float32)
-    out_ref[0, :] = (v @ onehot).astype(out_ref.dtype)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (b, kb), 0)
+    for r in range(g):
+        local = idx_ref[r:r + 1, :] - (pl.program_id(0) * g + r) * b  # [1, KB]
+        onehot_t = (rows == local).astype(jnp.float32)               # [B, KB]
+        v = vals_ref[r:r + 1, :].astype(jnp.float32)                 # [1, KB]
+        # HIGHEST: each value passes the MXU exactly (bitwise the scatter)
+        dense = jax.lax.dot_general(v, onehot_t, (((1,), (1,)), ((), ())),
+                                    precision=jax.lax.Precision.HIGHEST,
+                                    preferred_element_type=jnp.float32)
+        out_ref[r:r + 1, :] = dense.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -44,18 +53,21 @@ def sparse_dec_pallas(v2: jnp.ndarray, i2: jnp.ndarray, *, interpret: bool = Tru
     """v2/i2: [nb, kb] block-COO -> dense [nb*B] (block b owns indices
     [b*B, (b+1)*B))."""
     nb, kb = v2.shape
+    g = min(BLOCKS_PER_STEP, nb)
+    nbp = -(-nb // g) * g
+    pad = ((0, nbp - nb), (0, 0))
     out = pl.pallas_call(
         _dec_kernel,
-        grid=(nb,),
+        grid=(nbp // g,),
         in_specs=[
-            pl.BlockSpec((1, kb), lambda i: (i, 0)),
-            pl.BlockSpec((1, kb), lambda i: (i, 0)),
+            pl.BlockSpec((g, kb), lambda i: (i, 0)),
+            pl.BlockSpec((g, kb), lambda i: (i, 0)),
         ],
-        out_specs=[pl.BlockSpec((1, SPARSE_B), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nb, SPARSE_B), v2.dtype)],
+        out_specs=pl.BlockSpec((g, SPARSE_B), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((nbp, SPARSE_B), v2.dtype),
         interpret=interpret,
-    )(v2, i2)[0]
-    return out.reshape(-1)
+    )(jnp.pad(v2, pad), jnp.pad(i2, pad))
+    return out[:nb].reshape(-1)
 
 
 @jax.jit

@@ -10,10 +10,12 @@ which exist on TPU.  The TPU-native adaptation reformulates compaction as a
     values  = onehot @ x                           # MXU   [KB]
     indices = onehot @ arange(B) + block_base      # MXU   [KB]
 
-Each grid step compacts one B=512-element block into its KB capacity slots;
-empty slots produce (0, block_base) which decode treats as a no-op.  All
-operands are VMEM-resident (B*KB one-hot = 512×512 f32 = 1 MiB worst case,
-well under the ~16 MiB VMEM budget), and both matmul dims are 128-multiples.
+The running rank is itself a matmul (``mask @ triu(ones)``, since TPU
+kernels have no scan).  Each grid step compacts ``BLOCKS_PER_STEP``
+B=512-element blocks, each into its KB capacity slots; empty slots produce
+(0, block_base) which decode treats as a no-op.  All operands are
+VMEM-resident (the B×B triangle and one B*KB one-hot are 1 MiB each at
+worst, well under the ~16 MiB VMEM budget).
 
 Off-TPU the Pallas interpreter executes the grid loop step by step, and the
 one-hot's O(nb·kb·B) materialization makes the *emulation* the slowest thing
@@ -36,19 +38,39 @@ from jax.experimental import pallas as pl
 from .ref import SPARSE_B
 
 
+#: COO blocks per grid step: the TPU block-shape rule wants the row count
+#: of a block to divide by 8 (or to span the array)
+BLOCKS_PER_STEP = 8
+
+
 def _enc_kernel(x_ref, vals_ref, idx_ref, cnt_ref, *, kb: int, threshold: float):
-    b = x_ref.shape[1]
-    x = x_ref[0, :].astype(jnp.float32)
-    mask = jnp.abs(x) > threshold
-    rank = jnp.cumsum(mask.astype(jnp.int32)) - 1            # [B]
-    slots = jax.lax.broadcasted_iota(jnp.int32, (kb, b), 0)
-    ranks = jnp.broadcast_to(rank[None, :], (kb, b))
-    onehot = ((ranks == slots) & mask[None, :]).astype(jnp.float32)  # [KB, B]
-    vals_ref[0, :] = (onehot @ x).astype(vals_ref.dtype)
-    base = pl.program_id(0) * b
-    local = jax.lax.broadcasted_iota(jnp.float32, (b, 1), 0)  # exact ints < 2^24
-    idx_ref[0, :] = (onehot @ local)[:, 0].astype(jnp.int32) + base
-    cnt_ref[0, 0] = jnp.minimum(jnp.sum(mask.astype(jnp.int32)), kb)
+    g, b = x_ref.shape
+    x = x_ref[...].astype(jnp.float32)                        # [G, B]
+    mask = (jnp.abs(x) > threshold).astype(jnp.float32)
+    # inclusive prefix count as a matmul with an upper-triangular ones
+    # matrix (0/1 operands: exact at any MXU precision)
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (b, b), 0) <=
+           jax.lax.broadcasted_iota(jnp.int32, (b, b), 1)).astype(jnp.float32)
+    rank = jnp.dot(mask, tri, preferred_element_type=jnp.float32) - 1.0
+    slots = jax.lax.broadcasted_iota(jnp.int32, (kb, b), 0).astype(jnp.float32)
+    local = jax.lax.broadcasted_iota(jnp.int32, (1, b), 1).astype(jnp.float32)
+    nt = (((1,), (1,)), ((), ()))                             # a @ b.T
+    for r in range(g):
+        keep = mask[r:r + 1] > 0
+        onehot = ((rank[r:r + 1] == slots) & keep).astype(jnp.float32)
+        # HIGHEST: the selected values and indices (< 2^24) pass the MXU
+        # exactly, bitwise the XLA gather
+        vals = jax.lax.dot_general(x[r:r + 1], onehot, nt,
+                                   precision=jax.lax.Precision.HIGHEST,
+                                   preferred_element_type=jnp.float32)
+        idx = jax.lax.dot_general(local, onehot, nt,
+                                  precision=jax.lax.Precision.HIGHEST,
+                                  preferred_element_type=jnp.float32)
+        base = (pl.program_id(0) * g + r) * b
+        vals_ref[r:r + 1, :] = vals.astype(vals_ref.dtype)
+        idx_ref[r:r + 1, :] = idx.astype(jnp.int32) + base
+        cnt = jnp.sum(mask[r:r + 1], axis=1, keepdims=True).astype(jnp.int32)
+        cnt_ref[r:r + 1, :] = jnp.minimum(cnt, kb)
 
 
 @functools.partial(jax.jit, static_argnames=("kb", "threshold", "interpret"))
@@ -57,24 +79,26 @@ def sparse_enc_pallas(flat: jnp.ndarray, *, kb: int, threshold: float = 0.0,
     """flat: [nb*B] -> (values [nb*kb], indices int32 [nb*kb], counts int32 [nb])."""
     n = flat.shape[0]
     nb = n // SPARSE_B
-    x2 = flat.reshape(nb, SPARSE_B)
+    g = min(BLOCKS_PER_STEP, nb)
+    nbp = -(-nb // g) * g
+    x2 = jnp.pad(flat.reshape(nb, SPARSE_B), ((0, nbp - nb), (0, 0)))
     vals, idxs, cnts = pl.pallas_call(
         functools.partial(_enc_kernel, kb=kb, threshold=threshold),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, SPARSE_B), lambda i: (i, 0))],
+        grid=(nbp // g,),
+        in_specs=[pl.BlockSpec((g, SPARSE_B), lambda i: (i, 0))],
         out_specs=[
-            pl.BlockSpec((1, kb), lambda i: (i, 0)),
-            pl.BlockSpec((1, kb), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((g, kb), lambda i: (i, 0)),
+            pl.BlockSpec((g, kb), lambda i: (i, 0)),
+            pl.BlockSpec((g, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nb, kb), flat.dtype),
-            jax.ShapeDtypeStruct((nb, kb), jnp.int32),
-            jax.ShapeDtypeStruct((nb, 1), jnp.int32),
+            jax.ShapeDtypeStruct((nbp, kb), flat.dtype),
+            jax.ShapeDtypeStruct((nbp, kb), jnp.int32),
+            jax.ShapeDtypeStruct((nbp, 1), jnp.int32),
         ],
         interpret=interpret,
     )(x2)
-    return vals.reshape(-1), idxs.reshape(-1), cnts.reshape(-1)
+    return vals[:nb].reshape(-1), idxs[:nb].reshape(-1), cnts[:nb].reshape(-1)
 
 
 @functools.partial(jax.jit, static_argnames=("kb", "threshold"))

@@ -38,7 +38,7 @@ from ..models.transformer import layer_plan
 from . import hlo_analysis as HA
 from . import shardings as SH
 from . import steps as ST
-from .mesh import make_production_mesh, mesh_axis_sizes, set_mesh
+from .mesh import make_production_mesh, mesh_axis_sizes
 
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "results", "dryrun.json")
@@ -155,7 +155,7 @@ def lower_combo(arch: str, shape_name: str, multi_pod: bool,
     n_chips = mesh.devices.size
     stacked = model.supports_stacked
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered, meta = _build_lowered(cfg, model, shape_name, mesh, stacked)
         t_lower = time.time() - t0
         rec = {"arch": arch, "shape": shape_name,

@@ -14,33 +14,35 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 V5E_PEAK_FLOPS = 197e12      # bf16 FLOP/s per chip
 V5E_HBM_BW = 819e9           # bytes/s per chip
 V5E_ICI_BW = 50e9            # bytes/s per link (~per-direction)
 
 
-def set_mesh(mesh):
-    """Ambient-mesh context, version-compatible: ``jax.set_mesh`` landed
-    after 0.4.x; older jax sets the thread-local mesh by entering the Mesh
-    itself.  Both make bare-PartitionSpec constraints resolve."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], devices=None):
+    """Every mesh of this package is built here, with ``Auto`` axes: the
+    sharding code states layouts as ``NamedSharding`` placements and
+    ``with_sharding_constraint`` hints and leaves propagation to the
+    compiler (GSPMD).  ``jax.make_mesh`` defaults to ``Explicit`` axes,
+    under which a ``lax.scan`` carry must keep its sharded type exactly."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1):
-    """Whatever fits the local devices — CPU tests and the e2e example."""
+    """Whatever fits the local devices — CPU tests, the e2e example and
+    one host's chips."""
     n = len(jax.devices())
     mp = min(model_parallel, n)
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return make_mesh((n // mp, mp), ("data", "model"))
 
 
 def mesh_axis_sizes(mesh) -> dict:

@@ -8,6 +8,7 @@ continuous-batching parity pins compare against (DESIGN.md §7).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, Optional
 
 import jax
@@ -49,6 +50,15 @@ def _stablelm_smoke_4l() -> ModelConfig:
     return dataclasses.replace(stablelm_1_6b.config().smoke(), n_layers=4)
 
 
+def _stablelm_1_6b() -> ModelConfig:
+    """stablelm-2-1.6b at its published widths (24 layers, d 2048, 32 heads
+    × 64, ffn 5632, vocab 100352, bf16), with the Pallas flash prefill on
+    the served path — the preset that fills one v5e chip."""
+    from ..configs import stablelm_1_6b
+    return dataclasses.replace(stablelm_1_6b.config(), use_flash_attn=True)
+
+
+register_serve_model("stablelm-1.6b", _stablelm_1_6b)
 register_serve_model("stablelm-smoke-flash", _stablelm_smoke_flash)
 register_serve_model("stablelm-smoke", _stablelm_smoke)
 register_serve_model("recurrentgemma-smoke", _recurrentgemma_smoke)
@@ -149,15 +159,12 @@ def three_tier_qos(rate: Optional[int] = None,
         serve_per_tick=serve_per_tick)
 
 
-def sequential_decode(params, cfg: ModelConfig, prompt, gen: int,
-                      max_seq: int) -> List[int]:
-    """Per-request sequential greedy decode — the parity reference.
-
-    One jitted b=1 prefill then ``gen - 1`` jitted b=1 decode steps: the
-    exact program each slot of the continuous batch runs, dispatched the
-    pre-batching way.  Continuous-batched serving must reproduce this
-    token-for-token (bitwise) for every request, whatever the join/leave
-    interleaving."""
+@functools.lru_cache(maxsize=16)
+def _sequential_fns(cfg: ModelConfig, max_seq: int):
+    """The jitted b=1 prefill and decode step of one (config, cache length),
+    built once: every reference call after the first reuses their traces
+    and compiled executables (a full-width model compiles for tens of
+    seconds)."""
     from ..models import transformer
 
     @jax.jit
@@ -170,6 +177,19 @@ def sequential_decode(params, cfg: ModelConfig, prompt, gen: int,
         logits, cache = transformer.lm_decode(p, cfg, tok[None], cache)
         return jnp.argmax(logits[0], axis=-1).astype(jnp.int32), cache
 
+    return prefill, decode
+
+
+def sequential_decode(params, cfg: ModelConfig, prompt, gen: int,
+                      max_seq: int) -> List[int]:
+    """Per-request sequential greedy decode — the parity reference.
+
+    One jitted b=1 prefill then ``gen - 1`` jitted b=1 decode steps: the
+    exact program each slot of the continuous batch runs, dispatched the
+    pre-batching way.  Continuous-batched serving must reproduce this
+    token-for-token (bitwise) for every request, whatever the join/leave
+    interleaving."""
+    prefill, decode = _sequential_fns(cfg, max_seq)
     tok, cache = prefill(params, jnp.asarray(prompt, jnp.int32))
     out = [int(tok)]
     for _ in range(max(0, gen - 1)):

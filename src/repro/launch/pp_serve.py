@@ -22,8 +22,6 @@ from typing import Callable, Dict
 
 import jax
 import jax.numpy as jnp
-
-from ..jaxcompat import pvary, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..models import layers as L
@@ -81,8 +79,8 @@ def make_pp_serve_step(model: Model, mesh, shard_kv_seq: bool = False
                 mbs = x_all.reshape(n_pods, mb, 1, d)
                 outs = jnp.zeros_like(mbs)
                 buf = jnp.zeros((mb, 1, d), x_all.dtype)
-                buf = pvary(buf, ("pod",))
-                outs = pvary(outs, ("pod",))
+                buf = jax.lax.pcast(buf, ("pod",), to="varying")
+                outs = jax.lax.pcast(outs, ("pod",), to="varying")
                 new_caches = caches_l
                 perm = [(i, i + 1) for i in range(n_pods - 1)]
 
@@ -146,7 +144,7 @@ def make_pp_serve_step(model: Model, mesh, shard_kv_seq: bool = False
                 lambda leaf: P("pod", *([None] * (leaf.ndim - 1))), stack)
             cache_specs = jax.tree_util.tree_map(
                 lambda leaf: P("pod", *([None] * (leaf.ndim - 1))), groups)
-            x_out, new_groups = shard_map(
+            x_out, new_groups = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(stack_specs, cache_specs, P(None, None, None),
                           P("pod")),

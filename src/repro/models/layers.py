@@ -160,15 +160,17 @@ def attn_train(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
     v = shard(v, "batch", "seq", "kv_heads", None)
     if cfg.use_flash_attn and window is None and not cfg.logit_softcap:
         # Pallas flash attention (kernels/flash_attn.py): no S×S HBM tensor.
-        from ..kernels.flash_attn import flash_attention
+        from ..kernels.flash_attn import flash_attention, flash_blocks
         from ..kernels.ops import use_interpret
         h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-        q2 = q.transpose(0, 2, 1, 3).reshape(b * h, s, hd)
-        k2 = k.transpose(0, 2, 1, 3).reshape(b * kvh, s, hd)
-        v2 = v.transpose(0, 2, 1, 3).reshape(b * kvh, s, hd)
-        o2 = flash_attention(q2, k2, v2, causal=True, kv_groups=h // kvh,
-                             interpret=use_interpret())
-        out = o2.reshape(b, h, s, hd).transpose(0, 2, 1, 3)
+        sp, blk = flash_blocks(s)
+        pad = ((0, 0), (0, sp - s), (0, 0))
+        q2 = jnp.pad(q.transpose(0, 2, 1, 3).reshape(b * h, s, hd), pad)
+        k2 = jnp.pad(k.transpose(0, 2, 1, 3).reshape(b * kvh, s, hd), pad)
+        v2 = jnp.pad(v.transpose(0, 2, 1, 3).reshape(b * kvh, s, hd), pad)
+        o2 = flash_attention(q2, k2, v2, causal=True, bq=blk, bk=blk,
+                             kv_groups=h // kvh, interpret=use_interpret())
+        out = o2[:, :s].reshape(b, h, s, hd).transpose(0, 2, 1, 3)
         y = out.reshape(b, s, -1) @ p["wo"]
         return shard(y, "batch", "seq", None)
     if cfg.attn_additive_mask:

@@ -15,8 +15,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..jaxcompat import shard_map
-
 from .config import ModelConfig
 from .layers import dense_init, _act
 from .sharding import shard
@@ -199,7 +197,7 @@ def _apply_moe_shard_map(p: Dict, cfg: ModelConfig, x: jnp.ndarray, mesh
         y = jax.lax.psum(y, "model")                           # combine partials
         return y.astype(xl.dtype).reshape(bl, sl, d), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, None, None), P(), s_gate, s_up, s_down),
         out_specs=(P(bspec, None, None), P()),

@@ -17,8 +17,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..jaxcompat import pvary, shard_map
-
 from .config import ModelConfig
 from .layers import dense_init
 from .sharding import shard
@@ -240,8 +238,8 @@ def ssm_train_seq_parallel(p: Dict, cfg: ModelConfig, x: jnp.ndarray, mesh
         dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + dt_bias)
         xh = xs.reshape(bl, sl, h, hd)
         # scan carry must carry the body's varying manual axes
-        h_init = pvary(jnp.zeros((bl, h, n, hd), jnp.float32),
-                               tuple(mesh.axis_names))
+        h_init = jax.lax.pcast(jnp.zeros((bl, h, n, hd), jnp.float32),
+                               tuple(mesh.axis_names), to="varying")
         y0, h_loc, cum = _ssd_scan(cfg, lp, xh, B, C, dt, h_init)
 
         # cross-shard inclusive scan of (decay_prod, state)
@@ -270,7 +268,7 @@ def ssm_train_seq_parallel(p: Dict, cfg: ModelConfig, x: jnp.ndarray, mesh
         y = y * jax.nn.silu(z)
         return y @ w_out
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, "model", None), P(), P(), P(), P(), P(), P()),
         out_specs=P(bspec, "model", None),
@@ -332,8 +330,8 @@ def _ssm_prefill_seq_parallel(p: Dict, cfg: ModelConfig, x: jnp.ndarray, mesh
         xs, B, C = jnp.split(xbc_c, [d_inner, d_inner + n], axis=-1)
         dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + dt_bias)
         xh = xs.reshape(bl, sl, h, hd)
-        h_init = pvary(jnp.zeros((bl, h, n, hd), jnp.float32),
-                               tuple(mesh.axis_names))
+        h_init = jax.lax.pcast(jnp.zeros((bl, h, n, hd), jnp.float32),
+                               tuple(mesh.axis_names), to="varying")
         y0, h_loc, cum = _ssd_scan(cfg, lp, xh, B, C, dt, h_init)
 
         d_loc = jnp.exp(jnp.sum(dt * (-jnp.exp(A_log))[None, None, :], axis=1))
@@ -363,7 +361,7 @@ def _ssm_prefill_seq_parallel(p: Dict, cfg: ModelConfig, x: jnp.ndarray, mesh
         ).astype(conv_tail.dtype)
         return out, h_final, conv_final
 
-    out, h_final, conv_final = shard_map(
+    out, h_final, conv_final = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, "model", None), P(), P(), P(), P(), P(), P()),
         out_specs=(P(bspec, "model", None), P(bspec, None, None, None),

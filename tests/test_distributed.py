@@ -31,13 +31,13 @@ def _run(script: str) -> str:
 def test_shard_map_moe_matches_dense():
     _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.launch.mesh import set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models import ModelConfig
         from repro.models import moe as MOE
         from repro.models.sharding import sharding_rules
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         for n_experts in (4, 8):   # 4 -> intra-expert TP, 8 -> EP
             cfg = ModelConfig(name="t", arch_type="moe", n_layers=1,
                               d_model=32, n_heads=2, n_kv_heads=2, d_ff=64,
@@ -47,7 +47,7 @@ def test_shard_map_moe_matches_dense():
             p = MOE.moe_init(jax.random.PRNGKey(0), cfg)
             x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32))
             y_ref, aux_ref = MOE._apply_moe_dense(p, cfg, x)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 with sharding_rules(batch="data", __mesh__=mesh):
                     y_sm, aux_sm = jax.jit(
                         lambda p, x: MOE._apply_moe_shard_map(p, cfg, x, mesh)
@@ -67,12 +67,12 @@ def test_mini_dryrun_lowers_on_small_mesh():
     _run("""
         import jax
         from dataclasses import replace
-        from repro.launch.mesh import set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.models.model import build_model
         from repro.launch import steps as ST, shardings as SH
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         for arch in ("gemma3-4b", "mixtral-8x22b", "mamba2-130m"):
             cfg = get_config(arch).smoke()
             cfg = replace(cfg, vocab=512)
@@ -80,7 +80,7 @@ def test_mini_dryrun_lowers_on_small_mesh():
             stacked = model.supports_stacked
             pshape = ST.eval_params_shape(model, stacked)
             pspec = SH.stacked_param_shardings(cfg, mesh, pshape)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 # train
                 step = ST.make_train_step(model, mesh, stacked=stacked)
                 oshape = ST.eval_opt_shape(pshape)
@@ -106,7 +106,7 @@ def test_mini_dryrun_lowers_on_small_mesh():
 def test_seq_parallel_ssd_matches_reference():
     _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.launch.mesh import set_mesh
+        from repro.launch.mesh import make_mesh
         from dataclasses import replace
         from repro.models import ModelConfig
         from repro.models import ssm as SSM
@@ -120,9 +120,9 @@ def test_seq_parallel_ssd_matches_reference():
         x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 64))
         y_ref = SSM.ssm_train(p, cfg, x)
         _, cache_ref = SSM.ssm_prefill(p, cfg, x)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg_sp = replace(cfg, ssm_seq_parallel=True)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             with sharding_rules(batch="data", __mesh__=mesh):
                 y_sp = jax.jit(lambda p, x: SSM.ssm_train(p, cfg_sp, x))(p, x)
                 y_pf, cache_sp = jax.jit(
@@ -140,17 +140,12 @@ def test_seq_parallel_ssd_matches_reference():
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not hasattr(__import__("jax"), "shard_map"),
-    reason="partial-manual shard_map subgroups CHECK-fail inside jaxlib "
-           "0.4.x's SPMD partitioner (spmd_partitioner.cc:512); needs the "
-           "jax>=0.5 manual-axes path")
 def test_pp_pod_offload_serve():
     """Pipeline-parallel decode across the pod axis (Fig. 2 at pod scale):
     tokens and caches must match the plain stacked decode."""
     _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.launch.mesh import set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models import ModelConfig, build_model
         from repro.launch.pp_serve import make_pp_serve_step, pp_applicable
         cfg = ModelConfig(name="t", arch_type="dense", n_layers=4, d_model=64,
@@ -162,9 +157,9 @@ def test_pp_pod_offload_serve():
         lp, cache = m.prefill_stacked(sp, {"tokens": toks}, max_seq=20)
         nxt = jnp.argmax(lp, -1)
         ld_ref, cref = m.decode_step_stacked(sp, nxt, cache)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         assert pp_applicable(m, mesh)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             tok_out, cpp = jax.jit(make_pp_serve_step(m, mesh))(sp, nxt, cache)
         np.testing.assert_array_equal(np.asarray(tok_out),
                                       np.asarray(jnp.argmax(ld_ref, -1)))

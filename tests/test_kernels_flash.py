@@ -78,6 +78,42 @@ class TestServePathPrefill:
         np.testing.assert_allclose(np.asarray(o), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
+    def test_remainder_block_is_refused(self):
+        """A length that is not a block multiple would leave its tail rows
+        unwritten (NaN); the kernel refuses it instead."""
+        q = jnp.zeros((2, 600, 16))
+        with pytest.raises(ValueError, match="pad upstream"):
+            flash_attention(q, q, q, causal=True, bq=512, bk=512)
+
+    def test_unaligned_600_token_prefill(self):
+        """A 600-token prompt through the served prefill (``attn_train``'s
+        flash gate pads to the block and slices back) matches the
+        full-softmax oracle on the same q/k/v and the ``_sdpa`` path on
+        every row, the rows past 512 included."""
+        import dataclasses
+        from repro.configs import stablelm_1_6b
+        from repro.models import layers as L
+        s = 600
+        cfg = stablelm_1_6b.config().smoke()
+        p = L.attn_init(jax.random.PRNGKey(0), cfg)
+        x = jax.random.normal(jax.random.PRNGKey(1), (1, s, cfg.d_model))
+        flash = L.attn_train(p, dataclasses.replace(cfg, use_flash_attn=True),
+                             x, None)
+        sdpa = L.attn_train(p, cfg, x, None)
+        assert bool(jnp.all(jnp.isfinite(flash)))
+        np.testing.assert_allclose(np.asarray(flash), np.asarray(sdpa),
+                                   atol=2e-5, rtol=2e-5)
+        pos = jnp.arange(s, dtype=jnp.int32)[None]
+        q, k, v = L._qkv(p, cfg, x, pos)
+        h, hd = cfg.n_heads, cfg.resolved_head_dim
+        flat = lambda t: t.transpose(0, 2, 1, 3).reshape(-1, s, hd)  # noqa
+        ref = attn_ref(flat(q), flat(k), flat(v), causal=True,
+                       kv_groups=h // cfg.n_kv_heads)
+        ref_y = ref.reshape(1, h, s, hd).transpose(0, 2, 1, 3).reshape(
+            1, s, h * hd) @ p["wo"]
+        np.testing.assert_allclose(np.asarray(flash), np.asarray(ref_y),
+                                   atol=2e-5, rtol=2e-5)
+
 
 class TestServePathDecodeStep:
     """flash_decode_step vs attn_decode_ref — the cached-KV DECODE half:

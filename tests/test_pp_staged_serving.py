@@ -12,7 +12,7 @@ covers stage swap.  The acceptance contract pinned here:
   including mid-generation joins and leaves;
 * the staged hop chain computes the same tokens pp_serve's shard_map step
   does (the intra-process pipeline-parallel reference) — same split, two
-  transports;
+  transports (tests/sharding/test_pp_shard_map.py, on forged devices);
 * killing a MID-CHAIN stage mid-generation loses zero tokens and replays
   ONLY that stage's cache slice: the coordinator re-runs the dead stage's
   retained boundary activations through a standby's prefill/replay verbs
@@ -179,54 +179,6 @@ class TestStagedParity:
             for ans in got:
                 assert ans == _ref(params, cfg, [i + 11], 3)
         _assert_conservation(_coord(rt))
-
-
-@pytest.mark.slow
-@pytest.mark.skipif(
-    not hasattr(__import__("jax"), "shard_map"),
-    reason="partial-manual shard_map subgroups CHECK-fail inside jaxlib "
-           "0.4.x's SPMD partitioner (spmd_partitioner.cc:512); needs the "
-           "jax>=0.5 manual-axes path")
-def test_staged_hops_match_shard_map_pp_step():
-    """Same split, two transports: one decode step through the staged
-    stage_prefill/stage_decode hop chain computes the tokens pp_serve's
-    shard_map ppermute step does on the same params (the intra-process
-    pipeline-parallel reference, pod axis = stage axis)."""
-    from repro.launch.mesh import set_mesh
-    from repro.launch.pp_serve import make_pp_serve_step, pp_applicable
-    from repro.models import ModelConfig, build_model
-    from repro.models import transformer as T
-    cfg = ModelConfig(name="t", arch_type="dense", n_layers=4, d_model=64,
-                      n_heads=4, n_kv_heads=2, d_ff=128, vocab=97,
-                      dtype="float32")
-    m = build_model(cfg)
-    params = m.init(jax.random.PRNGKey(0))
-    sp = m.stack_params(params)
-    toks = jax.random.randint(jax.random.PRNGKey(1), (4, 12), 0, 97)
-    lp, cache = m.prefill_stacked(sp, {"tokens": toks}, max_seq=20)
-    nxt = jnp.argmax(lp, -1)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-    assert pp_applicable(m, mesh)
-    with set_mesh(mesh):
-        tok_pp, _ = jax.jit(make_pp_serve_step(m, mesh))(sp, nxt, cache)
-    # the among-device split of the same step: per-stage prefill chain on
-    # the prompt, then one boundary-activation decode hop through both
-    # stages
-    n_stages = 2
-    stage_p = [T.stage_params(params, cfg, k, n_stages)
-               for k in range(n_stages)]
-    x, caches = toks, []
-    for k in range(n_stages):
-        x, c = T.stage_prefill(stage_p[k], cfg, k, n_stages, x, 20)
-        caches.append(c)
-    assert np.array_equal(np.asarray(jnp.argmax(x[:, -1], -1)),
-                          np.asarray(nxt))
-    y = nxt.astype(jnp.int32)
-    for k in range(n_stages):
-        y, caches[k] = T.stage_decode(stage_p[k], cfg, k, n_stages, y,
-                                      caches[k])
-    np.testing.assert_array_equal(np.asarray(jnp.argmax(y, -1)),
-                                  np.asarray(tok_pp))
 
 
 class TestStageChaos:
