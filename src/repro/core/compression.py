@@ -314,6 +314,14 @@ def decode_stacked(buf: StreamBuffer, codec: str) -> StreamBuffer:
 # host-level batch helpers (one dispatch + one device fetch per group)
 # ---------------------------------------------------------------------------
 
+# The batch helpers run the stacked codec as ONE compiled program.  Run op
+# by op, each reshape between the frames' shape and their flat wire view is
+# an executable of its own, and the TPU compiler spends minutes on some of
+# them (a VGA stack [B, 480, 640, 3] -> [B, 921600]); inside one program
+# they merge with the kernels' own views.
+_encode_stacked_jit = jax.jit(encode_stacked, static_argnames="codec")
+_decode_stacked_jit = jax.jit(decode_stacked, static_argnames="codec")
+
 def _stack_tensors(bufs: Sequence[StreamBuffer]):
     """Stack per-position tensors/payloads across same-structure frames."""
     cols = zip(*[b.tensors for b in bufs])
@@ -346,7 +354,7 @@ def encode_batch(bufs: Sequence[StreamBuffer], codec: str
     n = len(bufs)
     stacked = StreamBuffer(tensors=_stack_tensors(bufs),
                            pts=jnp.int32(0), meta={})
-    wire, dropped = encode_stacked(stacked, codec)
+    wire, dropped = _encode_stacked_jit(stacked, codec)
     per_tensor = ([] if dropped is None else
                   np.asarray(dropped))            # [tensors, frames], 1 sync
     frames = _host_frames(wire.tensors, n)
@@ -376,7 +384,7 @@ def decode_batch(bufs: Sequence[StreamBuffer], codec: str
     n = len(bufs)
     stacked = StreamBuffer(tensors=_stack_tensors(bufs),
                            pts=jnp.int32(0), meta={})
-    dec = decode_stacked(stacked, codec)
+    dec = _decode_stacked_jit(stacked, codec)
     frames = _host_frames(dec.tensors, n)
     return [b.with_(tensors=t, meta=_strip_wire_meta(b.meta))
             for b, t in zip(bufs, frames)]

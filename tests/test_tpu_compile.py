@@ -132,6 +132,23 @@ def test_sparse_dec_kernel_at_vga(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
+@pytest.mark.parametrize("codec", ["quant8", "sparse"])
+def test_batched_codec_programs_at_vga(one_chip, compiled_kernels, codec):
+    """``encode_batch``/``decode_batch`` over two VGA frames: each one
+    program around the Pallas kernels (the frames' reshapes to and from the
+    flat wire view, compiled op by op, took the TPU compiler minutes)."""
+    frames = StreamBuffer(
+        tensors=(jax.ShapeDtypeStruct((2,) + VGA_FRAME, jnp.float32),),
+        pts=jax.ShapeDtypeStruct((), jnp.int32), meta={})
+    enc = comp._encode_stacked_jit.lower(_on(one_chip, frames),
+                                         codec=codec).compile()
+    wire, _ = jax.eval_shape(lambda f: comp.encode_stacked(f, codec), frames)
+    dec = comp._decode_stacked_jit.lower(_on(one_chip, wire),
+                                         codec=codec).compile()
+    assert "tpu_custom_call" in enc.as_text()
+    assert "tpu_custom_call" in dec.as_text()
+
+
 # ---------------------------------------------------------------------------
 # the served programs at full width
 # ---------------------------------------------------------------------------
