@@ -48,7 +48,7 @@ PROMPT_LENGTHS = (7, 64, 600)    # 600: not a multiple of the flash block
 GENS = (16, 8)                   # each client's first and second request
 N_LM_CLIENTS = 4
 MAX_TICKS = 64                   # ample: the longest client needs ~26 ticks
-VGA = (480, 640)                 # benchmarks/common.py BANDWIDTHS["mid_vga"]
+VGA = (480, 640)                 # the paper's mid band (Fig. 6/7)
 
 #: Fallback acceptance for a served token that differs from
 #: ``sequential_decode``: its logit under the b=1 reference, teacher-forced

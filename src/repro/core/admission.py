@@ -51,6 +51,8 @@ from collections import Counter, deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from . import tracing
+
 __all__ = ["TenantSpec", "QoSConfig", "AdmissionRecord", "AdmissionQueue",
            "DEFAULT_TENANT", "percentile_from_hist", "merge_tenant_stats"]
 
@@ -127,6 +129,9 @@ class AdmissionRecord:
     deadline: float = _INF          # absolute tick; _INF = no deadline
     priority: int = 1
     client_id: Optional[int] = None
+    #: ``tracing.stamp()`` at ingest: the request's arrival at the batcher
+    #: while the tracer is on, else 0
+    arrived_ns: int = 0
 
     def order_key(self) -> Tuple:
         """(priority, deadline, arrival) — the slot-admission sort key the
@@ -259,7 +264,8 @@ class AdmissionQueue:
         rec = AdmissionRecord(raw=raw, tenant=tenant_id,
                               seq=next(self._seq), enqueue_tick=now,
                               deadline=deadline, priority=priority,
-                              client_id=client_id)
+                              client_id=client_id,
+                              arrived_ns=tracing.stamp())
         ts.queue.append(rec)
         self._queued += 1
         if client_id is not None:

@@ -90,7 +90,7 @@ from .admission import AdmissionQueue, QoSConfig
 from .buffers import StreamBuffer, structure_key, unstack_buffers
 from .query import QueryServerEndpoint
 from . import compression as comp
-from . import netfault
+from . import netfault, tracing
 
 __all__ = ["BatchingPolicy", "QueryBatcher", "StreamingQueryBatcher",
            "StagedStreamingBatcher", "StageQueryBatcher",
@@ -105,6 +105,11 @@ DEFAULT_QUERY_BATCH = 8
 #: id — varies per frame, so leaving it in meta would split every stacking
 #: group down to singletons)
 _ROUTING_KEYS = ("client_id", "codec", "tenant_id", "dseq")
+
+
+def _nbytes(tree) -> int:
+    """Bytes of a pytree's array leaves, from their static shapes."""
+    return sum(int(leaf.nbytes) for leaf in jax.tree_util.tree_leaves(tree))
 
 
 @dataclass(frozen=True)
@@ -197,6 +202,8 @@ class QueryBatcher:
         self.fused_batches = 0
         self.fused_frames = 0
         self.orphaned = 0
+        #: bytes of the fused serves' answers fetched to the host
+        self.wire_d2h_bytes = 0
 
     # -- public API ------------------------------------------------------------
     def in_flight(self, client_id: int) -> bool:
@@ -579,11 +586,32 @@ class QueryBatcher:
         n = len(pairs)
         src = plan.query_sources[0].name
         frames_in = tuple({src: clean} for clean, _ in pairs)
-        serve = plan.compiled_serve_batch(codec=codec)
-        (wire_outs, app_outs, dropped), run.state = serve(
-            run.params, run.state, frames_in)
-        wire_outs, app_outs, dropped = jax.device_get(
-            (wire_outs, app_outs, dropped))
+        with tracing.span("wire.serve") as serve_span:
+            serve_span.set(n=n, codec=codec)
+            serve = plan.compiled_serve_batch(codec=codec)
+            with tracing.span("wire.dispatch"):
+                (wire_outs, app_outs, dropped), run.state = serve(
+                    run.params, run.state, frames_in)
+            with tracing.span("wire.fetch") as sp:
+                wire_outs, app_outs, dropped = jax.device_get(
+                    (wire_outs, app_outs, dropped))
+                nbytes = _nbytes((wire_outs, app_outs, dropped))
+                sp.set(bytes=nbytes)
+            self.wire_d2h_bytes += nbytes
+            with tracing.span("wire.route"):
+                self._route_wire(pairs, codec, wire_outs, app_outs, dropped)
+        self.batched_frames += n
+        self.fused_batches += 1
+        self.fused_frames += n
+        if n > 1:
+            self.batches += 1
+            run.bursts += 1
+            run.burst_frames += n
+
+    def _route_wire(self, pairs, codec: str, wire_outs, app_outs, dropped):
+        """Route a fused serve's fetched answers, one frame per request."""
+        run = self.run
+        n = len(pairs)
         base_codec = codec.partition(":")[0]
         wire_frames = {name: unstack_buffers(b, n)
                        for name, b in wire_outs.items()}
@@ -610,13 +638,6 @@ class QueryBatcher:
                 run.sink_log.setdefault(name, []).append(buf)
             run.last_outputs = outs_i
             run.frames += 1
-        self.batched_frames += n
-        self.fused_batches += 1
-        self.fused_frames += n
-        if n > 1:
-            self.batches += 1
-            run.bursts += 1
-            run.burst_frames += n
 
     def _route(self, frame_outs: Dict[str, StreamBuffer], routing: Dict):
         """Deliver one frame's captured outputs: serversink answers replay
@@ -649,6 +670,7 @@ class QueryBatcher:
                 "fused_batches": self.fused_batches,
                 "fused_frames": self.fused_frames,
                 "flush_orphans": self.orphaned,
+                "wire_d2h_bytes": self.wire_d2h_bytes,
                 "admitted_requests": sum(t["admitted"] for t in
                                          adm.values()),
                 "served_requests": sum(t["served"] for t in adm.values()),
@@ -718,6 +740,14 @@ class StreamingQueryBatcher(QueryBatcher):
         self.tokens_dropped = 0
         self.streams_started = 0
         self.streams_finished = 0
+        #: decode ticks that took at least one waiting stream into a slot
+        self.join_ticks = 0
+        #: occupied slots summed over decode ticks
+        self.slot_ticks = 0
+        #: bytes of the joiners' prefilled caches fetched to the host
+        self.admit_d2h_bytes = 0
+        #: bytes of the admit bundles shipped to the device on join ticks
+        self.admit_h2d_bytes = 0
 
     # -- introspection ---------------------------------------------------------
     def in_flight(self, client_id: int) -> bool:
@@ -782,51 +812,60 @@ class StreamingQueryBatcher(QueryBatcher):
         admission honors tenant priority, and since a slotted stream is
         never evicted before its ``finished`` lane fires, preemption only
         ever happens at generation boundaries)."""
-        finished = 0
-        elem = self._serve_elem()
-        params = self.run.params.get(elem.name, {})
-        if self._replay:
-            # hot-swap replay: streams orphaned by a committed reconfig
-            # re-prefill on the NEW epoch's params (greedy decode — the
-            # regeneration is bitwise what a fresh build answers)
-            replays, self._replay = self._replay, []
-            for rec in replays:
-                tok, cache = elem.host_prefill(params, rec["prompt"])
+        with tracing.span("lm.admit"):
+            finished = 0
+            elem = self._serve_elem()
+            params = self.run.params.get(elem.name, {})
+            if self._replay:
+                # hot-swap replay: streams orphaned by a committed reconfig
+                # re-prefill on the NEW epoch's params (greedy decode — the
+                # regeneration is bitwise what a fresh build answers)
+                replays, self._replay = self._replay, []
+                for rec in replays:
+                    req = rec["trace"]
+                    req.mark("prefill")
+                    with req:
+                        tok, cache = elem.host_prefill(params, rec["prompt"])
+                    req.mark("first_token")
+                    self.prefills += 1
+                    self.tokens_generated += 1
+                    rec["tokens"] = [tok]
+                    rec["remaining"] = max(0, rec["gen"] - 1)
+                    rec["cache"] = cache
+                    if rec["remaining"] <= 0:
+                        self._finish(rec)
+                        finished += 1
+                    else:
+                        self._waiting.append(rec)
+            adm = self.admission
+            while self.endpoint.alive:
+                self._ingest()
+                adm.expire()
+                recs = adm.take(1)
+                if not recs:
+                    break
+                arec = recs[0]
+                req = tracing.request(arec.arrived_ns)
+                clean, routing = self._decode(arec.raw)
+                gen = int(clean.meta.get("gen", 1))
+                req.mark("prefill")
+                with req:
+                    tok, cache = elem.host_prefill(params, clean.tensors[0])
+                req.mark("first_token")
                 self.prefills += 1
+                self.streams_started += 1
                 self.tokens_generated += 1
-                rec["tokens"] = [tok]
-                rec["remaining"] = max(0, rec["gen"] - 1)
-                rec["cache"] = cache
+                rec = {"routing": routing, "tokens": [tok], "prompt":
+                       clean.tensors[0], "gen": gen,
+                       "remaining": max(0, gen - 1), "cache": cache,
+                       "adm": arec, "trace": req}
+                self._track(rec)
                 if rec["remaining"] <= 0:
                     self._finish(rec)
                     finished += 1
                 else:
                     self._waiting.append(rec)
-        adm = self.admission
-        while self.endpoint.alive:
-            self._ingest()
-            adm.expire()
-            recs = adm.take(1)
-            if not recs:
-                break
-            arec = recs[0]
-            clean, routing = self._decode(arec.raw)
-            gen = int(clean.meta.get("gen", 1))
-            tok, cache = elem.host_prefill(params, clean.tensors[0])
-            self.prefills += 1
-            self.streams_started += 1
-            self.tokens_generated += 1
-            rec = {"routing": routing, "tokens": [tok], "prompt":
-                   clean.tensors[0], "gen": gen,
-                   "remaining": max(0, gen - 1), "cache": cache,
-                   "adm": arec}
-            self._track(rec)
-            if rec["remaining"] <= 0:
-                self._finish(rec)
-                finished += 1
-            else:
-                self._waiting.append(rec)
-        return finished
+            return finished
 
     def _next_waiting(self) -> Dict:
         """The waiting stream the next free slot goes to: plain FIFO when
@@ -844,43 +883,62 @@ class StreamingQueryBatcher(QueryBatcher):
         """ONE stateful dispatch over the whole slot table: waiting streams
         join under the admit mask, every active slot emits a token, spent
         slots leave — all inside the same jitted program."""
-        run = self.run
-        plan = run.pipe.plan
-        elem = self._serve_elem()
-        free = sorted(s for s in range(elem.slots) if s not in self._slots)
-        admits = []
-        while free and self._waiting:
-            rec = self._next_waiting()
-            slot = free.pop(0)
-            admits.append((slot, rec["tokens"][-1], rec["remaining"],
-                           rec["cache"]))
-            rec["cache"] = None     # lives in plan state from here on
-            self._slots[slot] = rec
-        if not self._slots:
-            return 0
-        src = plan.query_sources[0].name
-        sink = plan.query_sinks[0].name
-        serve = plan.compiled_serve_tick(run.state)
-        outputs, run.state = serve(run.params, run.state,
-                                   {src: elem.build_admit(admits)})
-        toks, emitted, finished = jax.device_get(outputs[sink].tensors)
-        self.decode_ticks += 1
-        run.frames += 1
-        n_active = int(emitted.sum())
-        self.batched_frames += n_active
-        if n_active > 1:
-            self.batches += 1
-        done = 0
-        for slot in sorted(self._slots):
-            rec = self._slots[slot]
-            if emitted[slot]:
-                rec["tokens"].append(int(toks[slot]))
-                self.tokens_generated += 1
-            if finished[slot]:
-                self._finish(rec)
-                del self._slots[slot]
-                done += 1
-        return done
+        with tracing.span("lm.decode") as sp:
+            run = self.run
+            plan = run.pipe.plan
+            elem = self._serve_elem()
+            free = sorted(s for s in range(elem.slots)
+                          if s not in self._slots)
+            admits = []
+            while free and self._waiting:
+                rec = self._next_waiting()
+                rec["trace"].mark("join")
+                slot = free.pop(0)
+                admits.append((slot, rec["tokens"][-1], rec["remaining"],
+                               rec["cache"]))
+                rec["cache"] = None     # lives in plan state from here on
+                self._slots[slot] = rec
+            if not self._slots:
+                return 0
+            sp.set(joins=len(admits), active=len(self._slots),
+                   slots=elem.slots)
+            self.slot_ticks += len(self._slots)
+            src = plan.query_sources[0].name
+            sink = plan.query_sinks[0].name
+            serve = plan.compiled_serve_tick(run.state)
+            bundle = elem.build_admit(admits)
+            if admits:
+                # counted from static shapes: the joiners' caches the admit
+                # build fetched and the bundle the serve call ships
+                fetched = _nbytes([cache for *_, cache in admits])
+                shipped = _nbytes(bundle.tensors)
+                self.join_ticks += 1
+                self.admit_d2h_bytes += fetched
+                self.admit_h2d_bytes += shipped
+                sp.set(d2h_bytes=fetched, h2d_bytes=shipped)
+            with tracing.span("lm.decode.dispatch"):
+                outputs, run.state = serve(run.params, run.state,
+                                           {src: bundle})
+            with tracing.span("lm.decode.fetch"):
+                toks, emitted, finished = jax.device_get(
+                    outputs[sink].tensors)
+            self.decode_ticks += 1
+            run.frames += 1
+            n_active = int(emitted.sum())
+            self.batched_frames += n_active
+            if n_active > 1:
+                self.batches += 1
+            done = 0
+            for slot in sorted(self._slots):
+                rec = self._slots[slot]
+                if emitted[slot]:
+                    rec["tokens"].append(int(toks[slot]))
+                    self.tokens_generated += 1
+                if finished[slot]:
+                    self._finish(rec)
+                    del self._slots[slot]
+                    done += 1
+            return done
 
     def _finish(self, rec: Dict):
         """Deliver one completed stream: all its tokens as ONE answer
@@ -888,9 +946,12 @@ class StreamingQueryBatcher(QueryBatcher):
         client-channel route — identical to the stateless routing path)."""
         routing = rec["routing"]
         sink = self.run.pipe.plan.query_sinks[0]
+        req = rec.pop("trace")
         answer = StreamBuffer(
             tensors=(np.asarray(rec["tokens"], np.int32),), meta=routing)
-        sink.apply(self.run.params.get(sink.name, {}), [answer])
+        with req, tracing.span("lm.finish"):
+            sink.apply(self.run.params.get(sink.name, {}), [answer])
+        req.finish()
         self.tokens_delivered += len(rec["tokens"])
         self.streams_finished += 1
         arec = rec.pop("adm", None)
@@ -955,6 +1016,10 @@ class StreamingQueryBatcher(QueryBatcher):
             "streams_started": self.streams_started,
             "streams_finished": self.streams_finished,
             "replays": self.replays,
+            "join_ticks": self.join_ticks,
+            "slot_ticks": self.slot_ticks,
+            "admit_d2h_bytes": self.admit_d2h_bytes,
+            "admit_h2d_bytes": self.admit_h2d_bytes,
         })
         return base
 
@@ -1337,7 +1402,7 @@ class StagedStreamingBatcher(StreamingQueryBatcher):
             gen = int(clean.meta.get("gen", 1))
             rec = {"routing": routing, "tokens": [],
                    "prompt": clean.tensors[0], "gen": gen, "remaining": 0,
-                   "adm": arec}
+                   "adm": arec, "trace": tracing.request(arec.arrived_ns)}
             self.streams_started += 1
             self._track(rec)
             finished += self._start_stream(rec, elem, params)

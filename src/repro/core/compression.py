@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import tracing
 from .buffers import Quant8Payload, SparsePayload, StreamBuffer
 
 __all__ = ["encode", "decode", "encode_stacked", "decode_stacked",
@@ -59,9 +60,11 @@ CODECS = ("none", "quant8", "sparse")
 #: stripped by decode (a decoded frame carries neither)
 _WIRE_META = ("codec", "sparse_dropped")
 
-# process-wide lossy-encode accounting (benchmarks / Runtime.stats surface
-# this; tests reset it)
-_CODEC_STATS = {"sparse_truncated_tensors": 0, "sparse_dropped_values": 0}
+# process-wide codec accounting (benchmarks / Runtime.stats surface this;
+# tests reset it): lossy encodes, and the batch helpers' calls with the
+# bytes they ship to and fetch from the device (from static shapes)
+_CODEC_STATS = {"sparse_truncated_tensors": 0, "sparse_dropped_values": 0,
+                "batch_calls": 0, "h2d_bytes": 0, "d2h_bytes": 0}
 
 
 def codec_stats() -> Dict[str, int]:
@@ -270,6 +273,7 @@ def decode(buf: StreamBuffer, codec: str) -> StreamBuffer:
 # stacked API (traceable — the fused serving dispatch calls these in-jit)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("codec_encode")
 def encode_stacked(buf: StreamBuffer, codec: str
                    ) -> Tuple[StreamBuffer, Optional[jnp.ndarray]]:
     """Encode a STACKED buffer (leading frame axis) with one kernel
@@ -295,6 +299,7 @@ def encode_stacked(buf: StreamBuffer, codec: str
     raise ValueError(f"unknown codec {codec!r}")
 
 
+@jax.named_scope("codec_decode")
 def decode_stacked(buf: StreamBuffer, codec: str) -> StreamBuffer:
     """Decode a STACKED wire buffer (leading frame axis) with one kernel
     dispatch per tensor; frame ``i`` is bitwise ``decode(frame_i)``."""
@@ -323,7 +328,12 @@ _encode_stacked_jit = jax.jit(encode_stacked, static_argnames="codec")
 _decode_stacked_jit = jax.jit(decode_stacked, static_argnames="codec")
 
 def _stack_tensors(bufs: Sequence[StreamBuffer]):
-    """Stack per-position tensors/payloads across same-structure frames."""
+    """Stack per-position tensors/payloads across same-structure frames;
+    host (numpy) leaves ship to the device here and are counted."""
+    _CODEC_STATS["batch_calls"] += 1
+    _CODEC_STATS["h2d_bytes"] += sum(
+        leaf.nbytes for b in bufs for leaf in jax.tree_util.tree_leaves(
+            b.tensors) if isinstance(leaf, np.ndarray))
     cols = zip(*[b.tensors for b in bufs])
     return tuple(jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *col)
                  for col in cols)
@@ -334,6 +344,8 @@ def _host_frames(stacked_tensors, n: int):
     frame (an eager slice per leaf per frame would pay ~a dispatch each —
     the cost batching exists to remove).  Numpy leaves are bitwise the same
     frames; downstream jit calls device_put them on entry."""
+    _CODEC_STATS["d2h_bytes"] += sum(
+        leaf.nbytes for leaf in jax.tree_util.tree_leaves(stacked_tensors))
     host = jax.device_get(stacked_tensors)
     return [jax.tree_util.tree_map(lambda l, _i=i: l[_i], host)
             for i in range(n)]
@@ -352,12 +364,17 @@ def encode_batch(bufs: Sequence[StreamBuffer], codec: str
     if base == "none":
         return [(b, b.nbytes()) for b in bufs]
     n = len(bufs)
-    stacked = StreamBuffer(tensors=_stack_tensors(bufs),
-                           pts=jnp.int32(0), meta={})
-    wire, dropped = _encode_stacked_jit(stacked, codec)
-    per_tensor = ([] if dropped is None else
-                  np.asarray(dropped))            # [tensors, frames], 1 sync
-    frames = _host_frames(wire.tensors, n)
+    with tracing.span("codec.batch") as sp:
+        sp.set(n=n, dir="enc", codec=codec)
+        with tracing.span("codec.stack"):
+            stacked = StreamBuffer(tensors=_stack_tensors(bufs),
+                                   pts=jnp.int32(0), meta={})
+        with tracing.span("codec.run"):
+            wire, dropped = _encode_stacked_jit(stacked, codec)
+        with tracing.span("codec.fetch"):
+            per_tensor = ([] if dropped is None else
+                          np.asarray(dropped))    # [tensors, frames], 1 sync
+            frames = _host_frames(wire.tensors, n)
     out = []
     for i, (buf, tensors) in enumerate(zip(bufs, frames)):
         meta = {**buf.meta, "codec": base}
@@ -382,9 +399,14 @@ def decode_batch(bufs: Sequence[StreamBuffer], codec: str
     if base == "none":
         return bufs  # mirror per-frame decode: "none" is a strict no-op
     n = len(bufs)
-    stacked = StreamBuffer(tensors=_stack_tensors(bufs),
-                           pts=jnp.int32(0), meta={})
-    dec = _decode_stacked_jit(stacked, codec)
-    frames = _host_frames(dec.tensors, n)
+    with tracing.span("codec.batch") as sp:
+        sp.set(n=n, dir="dec", codec=codec)
+        with tracing.span("codec.stack"):
+            stacked = StreamBuffer(tensors=_stack_tensors(bufs),
+                                   pts=jnp.int32(0), meta={})
+        with tracing.span("codec.run"):
+            dec = _decode_stacked_jit(stacked, codec)
+        with tracing.span("codec.fetch"):
+            frames = _host_frames(dec.tensors, n)
     return [b.with_(tensors=t, meta=_strip_wire_meta(b.meta))
             for b, t in zip(bufs, frames)]

@@ -11,10 +11,11 @@ cache merged in under the admit mask) and leave (slots freed when
 traced program only sees fixed slot-axis shapes (DESIGN.md §7).
 
 Parity-by-construction: the decode tick runs each slot as an independent
-``b=1`` ``lm_decode`` via ``lax.scan`` over the slot axis — the identical
-traced program a per-request sequential decode runs — and commits state
-with a ``where(active, new, old)`` select, so continuous-batched output is
-bitwise the sequential output regardless of join/leave order (pinned in
+``b=1`` ``lm_decode`` via ``jax.vmap`` over the slot axis — the same traced
+program a per-request sequential decode runs, each slot an independent row
+of every batched matmul — and commits state with a ``where(active, new,
+old)`` select, so continuous-batched output is bitwise the sequential
+output regardless of join/leave order (pinned in
 tests/test_model_serving.py).
 
 The host half (prefill, admit-bundle assembly) lives on the element too:
@@ -30,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import tracing
 from .buffers import StreamBuffer
 from .element import Element, PipelineContext, register_element
 from .formats import Caps
@@ -160,18 +162,21 @@ class ModelServeElement(Element):
             cache, token = st["cache"], st["token"]
             remaining, active = st["remaining"], st["active"]
         else:
-            treedef = jax.tree_util.tree_structure(self._cache_template())
-            admit_mask, admit_tok, admit_rem = admit[0], admit[1], admit[2]
-            admit_cache = jax.tree_util.tree_unflatten(treedef,
-                                                       list(admit[3:]))
+            with jax.named_scope("lm_admit_merge"):
+                treedef = jax.tree_util.tree_structure(self._cache_template())
+                admit_mask, admit_tok, admit_rem = admit[:3]
+                admit_cache = jax.tree_util.tree_unflatten(treedef,
+                                                           list(admit[3:]))
 
-            def merge(old, new):
-                m = admit_mask.reshape((-1,) + (1,) * (old.ndim - 1))
-                return jnp.where(m, new, old)
-            cache = jax.tree_util.tree_map(merge, st["cache"], admit_cache)
-            token = jnp.where(admit_mask, admit_tok, st["token"])
-            remaining = jnp.where(admit_mask, admit_rem, st["remaining"])
-            active = st["active"] | admit_mask
+                def merge(old, new):
+                    m = admit_mask.reshape((-1,) + (1,) * (old.ndim - 1))
+                    return jnp.where(m, new, old)
+                cache = jax.tree_util.tree_map(merge, st["cache"],
+                                               admit_cache)
+                token = jnp.where(admit_mask, admit_tok, st["token"])
+                remaining = jnp.where(admit_mask, admit_rem,
+                                      st["remaining"])
+                active = st["active"] | admit_mask
 
         # 2. decode tick: each slot is an independent b=1 lm_decode — the
         #    same traced program sequential per-request decode runs — vmapped
@@ -231,13 +236,17 @@ class ModelServeElement(Element):
         if self._prefill_jit is None:
             cfg, max_seq = self.cfg, self.max_seq
 
+            @jax.named_scope("lm_prefill")
             def prefill(p, toks):
                 logits, cache = transformer.lm_prefill(p, cfg, toks[None],
                                                        max_seq)
                 return jnp.argmax(logits[0], axis=-1).astype(jnp.int32), cache
             self._prefill_jit = jax.jit(prefill)
-        tok, cache = self._prefill_jit(params, jnp.asarray(prompt, jnp.int32))
-        return int(tok), cache
+        with tracing.span("lm.prefill") as sp:
+            sp.set(n=len(prompt))
+            tok, cache = self._prefill_jit(params,
+                                           jnp.asarray(prompt, jnp.int32))
+            return int(tok), cache
 
     def empty_admit(self) -> StreamBuffer:
         """No-join tick: a structurally TINY bundle (mask only, flagged by
@@ -276,18 +285,24 @@ class ModelServeElement(Element):
         mask are zero (ignored by the masked merge)."""
         if not admits:
             return self.empty_admit()
-        base = self._zero_admit()
-        mask = base[0].copy()
-        tok = base[1].copy()
-        rem = base[2].copy()
-        leaves = [l.copy() for l in base[3:]]
-        for slot, t, r, cache in admits:
-            mask[slot] = True
-            tok[slot] = t
-            rem[slot] = r
-            for dst, src in zip(leaves, jax.tree_util.tree_leaves(
-                    jax.device_get(cache))):
-                dst[slot] = src
+        with tracing.span("lm.admit.build") as sp:
+            sp.set(n=len(admits))
+            with tracing.span("lm.admit.copy"):
+                base = self._zero_admit()
+                mask = base[0].copy()
+                tok = base[1].copy()
+                rem = base[2].copy()
+                leaves = [l.copy() for l in base[3:]]
+            for slot, t, r, cache in admits:
+                with tracing.span("lm.admit.fetch"):
+                    host = jax.device_get(cache)
+                with tracing.span("lm.admit.copy"):
+                    mask[slot] = True
+                    tok[slot] = t
+                    rem[slot] = r
+                    for dst, src in zip(leaves,
+                                        jax.tree_util.tree_leaves(host)):
+                        dst[slot] = src
         return StreamBuffer(tensors=(mask, tok, rem, *leaves), meta={})
 
 

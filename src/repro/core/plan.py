@@ -636,6 +636,7 @@ class ExecutionPlan:
         fns = self._cache()["fns"]
         key = ("serve_tick", donate, self.serve_stage, state_key)
         if key not in fns:
+            @jax.named_scope("lm_serve_tick")
             def serve_tick(params, state, inputs, _self=self):
                 return _self.run(params, state, inputs,
                                  hoist_io=True, hoist_queries=True)

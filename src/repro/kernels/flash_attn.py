@@ -123,6 +123,7 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 512,
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "kv_groups"))
+@jax.named_scope("flash_decode")
 def flash_decode_step(q, k, v, pos, *, bk: int = 128, kv_groups: int = 1):
     """One cached-KV decode step with online softmax: q is a SINGLE query
     position attending to ``k[:, :pos+1]`` of a ring/linear KV cache.

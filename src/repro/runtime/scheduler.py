@@ -101,7 +101,7 @@ from ..core.broker import Broker, BrokerError
 from ..core.buffers import (StreamBuffer, stack_buffers, structure_key,
                             unstack_buffers)
 from ..core.element import Element
-from ..core import netfault
+from ..core import netfault, tracing
 from ..core.pipeline import Pipeline
 from ..core.plan import PendingQuery
 from ..core.pubsub import Channel, MqttSink, MqttSrc
@@ -556,7 +556,8 @@ class Runtime:
         returned in input order.  Bitwise per-request ``encode`` (payload,
         meta, wire bytes — core/compression.py batch contract)."""
         from ..core import compression as comp
-        return self._codec_round(pairs, comp.encode_batch)
+        with tracing.span("sched.encode_round"):
+            return self._codec_round(pairs, comp.encode_batch)
 
     def _dispatch_round(self, fresh: List[Tuple[_PipeRun, PendingQuery]]
                         ) -> List[Tuple[_PipeRun, PendingQuery]]:
@@ -873,9 +874,10 @@ class Runtime:
         """Decode a drain round's raw answers, batched per (codec,
         structure) group on the fused path, per frame on the legacy one."""
         from ..core import compression as comp
-        if not self.fused_wire:
-            return [comp.decode(raw, qc.codec) for qc, raw in pairs]
-        return self._codec_round(pairs, comp.decode_batch)
+        with tracing.span("sched.decode_answers"):
+            if not self.fused_wire:
+                return [comp.decode(raw, qc.codec) for qc, raw in pairs]
+            return self._codec_round(pairs, comp.decode_batch)
 
     # -- burst draining ----------------------------------------------------------
     def _burst_size(self, run: _PipeRun) -> int:
@@ -960,70 +962,74 @@ class Runtime:
             self._deliver_frame(run, outputs)
 
     def tick(self):
-        self.ticks += 1
-        if self.fabric is not None:
-            # advance the fault clock first: frames the network held
-            # (delay/reorder) from earlier ticks land before anything runs,
-            # and this tick's scripted partitions take effect
-            self.fabric.step(self.ticks)
-        self._ntp_ref.advance(self.tick_ns)
-        for dev in self.devices:
-            dev.clock.advance(self.tick_ns)
-        self._heartbeat_and_lease()
-        # tick boundary: pending reconfigurations commit (or drain/roll
-        # back) BEFORE any frame of this tick starts — a swap never lands
-        # under a frame mid-walk
-        self.reconfig.step()
-        # elastic serving (DESIGN.md §9): autoscalers read the broker's
-        # scaling signal AFTER pending reconfigs settled and request their
-        # own add/remove reconfigs — which commit through the same §6
-        # lifecycle on later ticks (autoscaling is a reconfig, not a new
-        # mechanism)
-        for scaler in list(self.autoscalers):
-            scaler.step()
-        self._load_bumps.clear()
-        self._expire_parked()
-        # frames parked from earlier ticks go first (a server may be back);
-        # their pipelines must not start a second concurrent frame
-        pending = self._retry_parked()
-        # streams mid-generation re-enter the drain: a live server keeps
-        # decoding them (one tick = one token per active stream); a dead one
-        # routes them through the same dispatch-or-park failover as any
-        # in-flight query (prefill replay on the survivor)
-        inflight, self._inflight = self._inflight, []
-        pending.extend(inflight)
-        busy = {id(run) for run, _ in pending} | \
-               {id(run) for run, _, _ in self._parked}
-        fresh: List[Tuple[_PipeRun, PendingQuery]] = []
-        for dev in self.devices:
-            if not dev.alive:
-                continue  # a dead device runs nothing (chaos harness)
-            for run in dev.runs:
-                if run.retired:
-                    continue  # decommissioned by a reconfiguration
-                if any(isinstance(e, TensorQueryServerSrc)
-                       for e in run.pipe.elements.values()):
-                    continue  # servers run batched/inline, driven by clients
-                if id(run) in busy:
-                    run.skipped += 1  # frame still in flight from a past tick
-                    continue
-                if not self._ready(run):
-                    run.skipped += 1
-                    continue
-                if run.pipe.plan.has_query_clients and self.batching.enabled:
-                    paused = self._begin_deferred(run)
-                    if paused is not None:
-                        fresh.append(paused)
-                    continue
-                n = self._burst_size(run)
-                if n > 1:
-                    self._run_burst(run, n)
-                else:
-                    self._run_once(run)
-        # the whole round's request encodes batch into one codec dispatch
-        # per group before anything ships (fused path; arrival order kept)
-        pending.extend(self._dispatch_round(fresh))
-        self._drain_queries(pending)
+        with tracing.span("sched.tick"):
+            self.ticks += 1
+            if self.fabric is not None:
+                # advance the fault clock first: frames the network held
+                # (delay/reorder) from earlier ticks land before anything runs,
+                # and this tick's scripted partitions take effect
+                self.fabric.step(self.ticks)
+            self._ntp_ref.advance(self.tick_ns)
+            for dev in self.devices:
+                dev.clock.advance(self.tick_ns)
+            self._heartbeat_and_lease()
+            # tick boundary: pending reconfigurations commit (or drain/roll
+            # back) BEFORE any frame of this tick starts — a swap never lands
+            # under a frame mid-walk
+            self.reconfig.step()
+            # elastic serving (DESIGN.md §9): autoscalers read the broker's
+            # scaling signal AFTER pending reconfigs settled and request their
+            # own add/remove reconfigs — which commit through the same §6
+            # lifecycle on later ticks (autoscaling is a reconfig, not a new
+            # mechanism)
+            for scaler in list(self.autoscalers):
+                scaler.step()
+            self._load_bumps.clear()
+            self._expire_parked()
+            # frames parked from earlier ticks go first (a server may be
+            # back); their pipelines must not start a second concurrent frame
+            pending = self._retry_parked()
+            # streams mid-generation re-enter the drain: a live server keeps
+            # decoding them (one tick = one token per active stream); a dead
+            # one routes them through the same dispatch-or-park failover as
+            # any in-flight query (prefill replay on the survivor)
+            inflight, self._inflight = self._inflight, []
+            pending.extend(inflight)
+            busy = {id(run) for run, _ in pending} | \
+                   {id(run) for run, _, _ in self._parked}
+            fresh: List[Tuple[_PipeRun, PendingQuery]] = []
+            for dev in self.devices:
+                if not dev.alive:
+                    continue  # a dead device runs nothing (chaos harness)
+                for run in dev.runs:
+                    if run.retired:
+                        continue  # decommissioned by a reconfiguration
+                    if any(isinstance(e, TensorQueryServerSrc)
+                           for e in run.pipe.elements.values()):
+                        # servers run batched/inline, driven by clients
+                        continue
+                    if id(run) in busy:
+                        # frame still in flight from a past tick
+                        run.skipped += 1
+                        continue
+                    if not self._ready(run):
+                        run.skipped += 1
+                        continue
+                    if run.pipe.plan.has_query_clients and \
+                            self.batching.enabled:
+                        paused = self._begin_deferred(run)
+                        if paused is not None:
+                            fresh.append(paused)
+                        continue
+                    n = self._burst_size(run)
+                    if n > 1:
+                        self._run_burst(run, n)
+                    else:
+                        self._run_once(run)
+            # the whole round's request encodes batch into one codec dispatch
+            # per group before anything ships (fused path; arrival order kept)
+            pending.extend(self._dispatch_round(fresh))
+            self._drain_queries(pending)
 
     def run(self, n_ticks: int):
         for _ in range(n_ticks):

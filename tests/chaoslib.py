@@ -39,8 +39,7 @@ Fault vocabulary:
   planes fail independently).
 * ``at(tick, fn, label)`` — escape hatch for bespoke faults.
 
-All mutations funnel through ``_kill``/``_revive`` so tests, benchmarks
-(``benchmarks/bench_failover.py``), and examples
+All mutations funnel through ``_kill``/``_revive`` so tests and examples
 (``examples/failover_offloading.py``) exercise exactly the code paths the
 runtime's failover fabric watches — one copy of the fault semantics.
 """

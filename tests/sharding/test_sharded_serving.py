@@ -1,6 +1,6 @@
 """Mesh-sharded compiled serving (DESIGN.md §4).
 
-Contract pinned here (and gated in benchmarks/bench_sharded_serving.py):
+Contract pinned here:
 laying a serving batch out along a mesh's data axes may only change WHERE
 frames execute, never what any client sees —
 

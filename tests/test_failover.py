@@ -6,7 +6,7 @@ drive the failover fabric with the deterministic chaos harness
 (tests/chaoslib.py): scripted kills/revivals at chosen ticks, no
 wall-clock, no flakes.
 
-Acceptance contract pinned here (and gated in benchmarks/bench_failover.py):
+Acceptance contract pinned here:
 killing a serving device mid-batch loses ZERO client requests — orphaned
 queries re-dispatch to a surviving server and every answer is bitwise what
 the fault-free run produces; frames with no live server park and recover

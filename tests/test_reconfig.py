@@ -7,7 +7,7 @@ serving path, commits at a tick boundary with queued frames and in-flight
 queries carried across, and rolls back cleanly when the prepare fails or
 the target dies mid-warm.
 
-Acceptance contract pinned here (and gated in benchmarks/bench_reconfig.py):
+Acceptance contract pinned here:
 
 * the hot swap commits at a tick boundary with ZERO frames lost and every
   post-commit answer bitwise identical to a freshly-built pipeline at
