@@ -13,6 +13,11 @@ prefill: 56s of the 56–71s memory term).  Flash attention keeps each
 HBM traffic per layer drops to Q+K+V+O (+negligible scratch), i.e. the
 attention term leaves the memory roofline entirely on TPU.  Validated in
 interpret mode against the pure-jnp oracle (models.layers._sdpa semantics).
+
+This is the prefill kernel only.  Cached-KV decode (one query row) has no
+S×S tensor to avoid: its [H, S_cache] score row is no larger than the KV
+cache it reads whenever H / KV <= head_dim, so ``models.layers.attn_decode``
+runs one-piece masked attention (``_sdpa``) for flash configurations too.
 """
 from __future__ import annotations
 
@@ -121,57 +126,3 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 512,
         interpret=interpret,
     )(q, k, v)
 
-
-@functools.partial(jax.jit, static_argnames=("bk", "kv_groups"))
-@jax.named_scope("flash_decode")
-def flash_decode_step(q, k, v, pos, *, bk: int = 128, kv_groups: int = 1):
-    """One cached-KV decode step with online softmax: q is a SINGLE query
-    position attending to ``k[:, :pos+1]`` of a ring/linear KV cache.
-
-    q: [BH, dk]; k: [BKV, Sk, dk]; v: [BKV, Sk, dv]; pos: int32 scalar
-    (last valid cache index) -> [BH, dv].
-
-    Decode attention is memory-roofline-bound on the KV stream (one query
-    row cannot feed the MXU) — the win is never materializing the [BH, Sk]
-    score row in one piece at long context.  ``lax.scan`` over KV blocks
-    carries the flash (m, l, acc) triple, so per-block peak memory is
-    [BH, bk] regardless of Sk; masking ``idx > pos`` inside each block
-    makes the result exact for any fill level.  GQA repeats kv heads into
-    the q-head axis (a [BKV → BH] broadcast of the small cache slice, not
-    an S×S tensor).  f32 accumulation throughout, cast back to q.dtype —
-    bitwise the serve-path reference (kernels/ref.py attn_decode_ref)."""
-    bh, dk = q.shape
-    bkv, sk, dv = v.shape
-    g = kv_groups
-    if g > 1:
-        k = jnp.repeat(k, g, axis=0)
-        v = jnp.repeat(v, g, axis=0)
-    bk = min(bk, sk)
-    nb = -(-sk // bk)
-    pad = nb * bk - sk
-    if pad:
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
-    qf = q.astype(jnp.float32) * (dk ** -0.5)             # [BH, dk]
-    kb = k.astype(jnp.float32).reshape(bh, nb, bk, dk).transpose(1, 0, 2, 3)
-    vb = v.astype(jnp.float32).reshape(bh, nb, bk, dv).transpose(1, 0, 2, 3)
-    base = jnp.arange(nb, dtype=jnp.int32) * bk
-
-    def block(carry, xs):
-        m, l, acc = carry
-        kj, vj, b0 = xs
-        s = jnp.einsum("hd,hkd->hk", qf, kj)              # [BH, bk]
-        idx = b0 + jnp.arange(bk, dtype=jnp.int32)
-        s = jnp.where((idx <= pos)[None, :], s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, -1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, -1, keepdims=True)
-        acc = acc * alpha + jnp.einsum("hk,hkd->hd", p, vj)
-        return (m_new, l, acc), None
-
-    init = (jnp.full((bh, 1), NEG_INF, jnp.float32),
-            jnp.zeros((bh, 1), jnp.float32),
-            jnp.zeros((bh, dv), jnp.float32))
-    (m, l, acc), _ = jax.lax.scan(block, init, (kb, vb, base))
-    return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)

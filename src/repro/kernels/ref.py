@@ -117,9 +117,10 @@ def attn_ref(q, k, v, *, causal: bool = True, kv_groups: int = 1):
 
 
 def attn_decode_ref(q, k, v, pos, *, kv_groups: int = 1):
-    """Full-softmax reference for ``flash_decode_step``: q [BH, dk] (one
-    query position), cached k/v [BKV, Sk, d*], ``pos`` the last valid cache
-    index -> [BH, dv]."""
+    """Full-softmax reference for the serve path's decode attention
+    (``models.layers.attn_decode``, one-piece ``_sdpa`` over the cache):
+    q [BH, dk] (one query position), cached k/v [BKV, Sk, d*], ``pos`` the
+    last valid cache index -> [BH, dv]."""
     dk = q.shape[-1]
     if kv_groups > 1:
         k = jnp.repeat(k, kv_groups, axis=0)

@@ -24,8 +24,8 @@ __all__ = ["serve_pipeline", "client_pipeline", "sequential_decode",
 
 
 def _stablelm_smoke_flash() -> ModelConfig:
-    """Small dense transformer with flash attention on BOTH serve paths
-    (prefill via attn_train's flash gate, decode via flash_decode_step)."""
+    """Small dense transformer with the Pallas flash prefill (attn_train's
+    flash gate); decode attends in one piece (``_sdpa``), as every preset's."""
     from ..configs import stablelm_1_6b
     return dataclasses.replace(stablelm_1_6b.config().smoke(),
                                use_flash_attn=True)

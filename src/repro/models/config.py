@@ -89,7 +89,7 @@ class ModelConfig:
     mla_fused_qk: bool = False          # one concat QK einsum (no 2nd S×S
                                         # dot + transpose + add pass)
     use_flash_attn: bool = False        # Pallas flash-attention for global
-                                        # causal layers (TPU production path)
+                                        # causal layers' prefill (TPU path)
     attn_additive_mask: bool = False    # additive causal bias instead of
                                         # boolean select (fewer S×S passes)
 

@@ -255,23 +255,12 @@ def attn_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray, cache: Dict,
     valid = (kpos <= pos) & (kpos >= 0)
     if window is not None:
         valid &= pos - kpos < window
-    if cfg.use_flash_attn and window is None and not cfg.logit_softcap \
-            and not cfg.kv_cache_quant:
-        # serve-path decode step (kernels/flash_attn.py): online softmax
-        # over the cached KV stream, no [B, S_cache] score row in one
-        # piece — the cached-KV twin of attn_train's flash gate, with the
-        # same layout transform ([B,s,h,hd] -> flat [B·h, ...])
-        from ..kernels.flash_attn import flash_decode_step
-        h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-        q2 = q.transpose(0, 2, 1, 3).reshape(b * h, hd)
-        k2 = ck.transpose(0, 2, 1, 3).reshape(b * kvh, size, hd)
-        v2 = cv.transpose(0, 2, 1, 3).reshape(b * kvh, size, hd)
-        o2 = flash_decode_step(q2, k2, v2, pos, kv_groups=h // kvh)
-        out = o2.reshape(b, h, 1, hd).transpose(0, 2, 1, 3)
-    else:
-        mask = jnp.broadcast_to(valid[None, None, :], (b, 1, size))
-        out = _sdpa(q, ck, cv, mask, cfg.logit_softcap, cfg.n_heads,
-                    cfg.n_kv_heads, f32_logits=cfg.attn_f32_logits)
+    # one piece for every configuration: the [B, H, S_cache] score row is
+    # no larger than the cache it reads whenever H / KV <= hd, so a
+    # blockwise (flash) sweep buys nothing at decode
+    mask = jnp.broadcast_to(valid[None, None, :], (b, 1, size))
+    out = _sdpa(q, ck, cv, mask, cfg.logit_softcap, cfg.n_heads,
+                cfg.n_kv_heads, f32_logits=cfg.attn_f32_logits)
     y = out.reshape(b, 1, -1) @ p["wo"]
     return shard(y, "batch", None, None), \
         (new_cache if new_cache is not None else {"k": ck, "v": cv})

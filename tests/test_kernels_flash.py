@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.kernels.flash_attn import flash_attention, flash_decode_step
+from repro.kernels.flash_attn import flash_attention
 from repro.kernels.ref import attn_decode_ref, attn_ref
 
 
@@ -115,25 +115,39 @@ class TestServePathPrefill:
                                    atol=2e-5, rtol=2e-5)
 
 
+def _serve_decode(q, k, v, pos, groups=1):
+    """The serve path's decode attention as ``attn_decode`` runs it (the
+    one-piece ``_sdpa`` over the cache in its [B, S_cache, KV, hd] layout,
+    masked to the valid rows, float32 logits), on ``attn_decode_ref``'s
+    flat layout: q [H, d], k/v [H // groups, Sk, d] -> [H, d]."""
+    from repro.models import layers as L
+    sk = k.shape[1]
+    valid = jnp.arange(sk, dtype=jnp.int32) <= pos
+    mask = jnp.broadcast_to(valid[None, None, :], (1, 1, sk))
+    out = L._sdpa(q[None, None], k.transpose(1, 0, 2)[None],
+                  v.transpose(1, 0, 2)[None], mask, None, q.shape[0],
+                  k.shape[0], f32_logits=True)
+    return out[0, 0]
+
+
 class TestServePathDecodeStep:
-    """flash_decode_step vs attn_decode_ref — the cached-KV DECODE half:
-    one query row against a partially filled cache, swept over fill levels,
-    block sizes and GQA groups."""
+    """The served decode attention vs attn_decode_ref — the cached-KV
+    DECODE half: one query row against a partially filled cache, swept
+    over fill levels, cache lengths and GQA groups."""
 
     @given(st.sampled_from([8, 31, 63, 64, 100, 127]),
-           st.sampled_from([32, 128]), st.sampled_from([1, 2, 4]),
+           st.sampled_from([128, 160, 256]), st.sampled_from([1, 2, 4]),
            st.integers(0, 2 ** 30))
     @settings(max_examples=12, deadline=None)
-    def test_matches_decode_ref(self, pos, bk, groups, seed):
-        bh, sk, dk, dv = 4, 128, 32, 48
+    def test_matches_decode_ref(self, pos, sk, groups, seed):
+        bh, d = 4, 32
         ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-        q = jax.random.normal(ks[0], (bh, dk))
-        k = jax.random.normal(ks[1], (bh // groups, sk, dk))
-        v = jax.random.normal(ks[2], (bh // groups, sk, dv))
-        o = flash_decode_step(q, k, v, jnp.int32(pos), bk=bk,
-                              kv_groups=groups)
+        q = jax.random.normal(ks[0], (bh, d))
+        k = jax.random.normal(ks[1], (bh // groups, sk, d))
+        v = jax.random.normal(ks[2], (bh // groups, sk, d))
+        o = _serve_decode(q, k, v, jnp.int32(pos), groups)
         ref = attn_decode_ref(q, k, v, pos, kv_groups=groups)
-        assert o.shape == (bh, dv)
+        assert o.shape == (bh, d)
         np.testing.assert_allclose(np.asarray(o), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
@@ -148,12 +162,12 @@ class TestServePathDecodeStep:
         q = jax.random.normal(ks[0], (bh, dk))
         k = jax.random.normal(ks[1], (bh, sk, dk))
         v = jax.random.normal(ks[2], (bh, sk, dk))
-        o1 = flash_decode_step(q, k, v, jnp.int32(pos), bk=32)
+        o1 = _serve_decode(q, k, v, jnp.int32(pos))
         noise = 100.0 * jax.random.normal(ks[3], (bh, sk, dk))
         tail = (jnp.arange(sk) > pos)[None, :, None]
         k2 = jnp.where(tail, k + noise, k)
         v2 = jnp.where(tail, v + noise, v)
-        o2 = flash_decode_step(q, k2, v2, jnp.int32(pos), bk=32)
+        o2 = _serve_decode(q, k2, v2, jnp.int32(pos))
         np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
 
     def test_pos_zero_attends_only_first_row(self):
@@ -162,20 +176,56 @@ class TestServePathDecodeStep:
         q = jax.random.normal(ks[0], (bh, dk))
         k = jax.random.normal(ks[1], (bh, sk, dk))
         v = jax.random.normal(ks[2], (bh, sk, dk))
-        o = flash_decode_step(q, k, v, jnp.int32(0), bk=32)
+        o = _serve_decode(q, k, v, jnp.int32(0))
         np.testing.assert_allclose(np.asarray(o), np.asarray(v[:, 0]),
                                    atol=1e-5)
 
     def test_decode_step_agrees_with_prefill_last_row(self):
-        """Cross-kernel consistency: decoding position ``pos`` against the
-        cache equals the last row of a causal prefill over the same
-        sequence — the handoff the serve path makes at admission."""
-        bh, s, d = 4, 64, 32
-        ks = jax.random.split(jax.random.PRNGKey(3), 3)
-        q = jax.random.normal(ks[0], (bh, s, d))
-        k = jax.random.normal(ks[1], (bh, s, d))
-        v = jax.random.normal(ks[2], (bh, s, d))
-        pre = flash_attention(q, k, v, causal=True, bq=64, bk=64)
-        step = flash_decode_step(q[:, -1], k, v, jnp.int32(s - 1), bk=64)
-        np.testing.assert_allclose(np.asarray(pre[:, -1]), np.asarray(step),
+        """Cross-path consistency on a flash preset: ``attn_decode`` at
+        position ``s - 1`` against a cache holding the first ``s - 1``
+        keys (garbage beyond) equals the last row of ``attn_train``'s
+        flash prefill over the same sequence — the handoff the serve path
+        makes at admission."""
+        import dataclasses
+        from repro.configs import stablelm_1_6b
+        from repro.models import layers as L
+        s, size = 64, 96
+        cfg = dataclasses.replace(stablelm_1_6b.config().smoke(),
+                                  use_flash_attn=True)
+        p = L.attn_init(jax.random.PRNGKey(0), cfg)
+        x = jax.random.normal(jax.random.PRNGKey(1), (1, s, cfg.d_model))
+        pre = L.attn_train(p, cfg, x, None)
+        pos = jnp.arange(s, dtype=jnp.int32)[None]
+        _, k, v = L._qkv(p, cfg, x, pos)
+        junk = jax.random.normal(jax.random.PRNGKey(2),
+                                 (1, size) + k.shape[2:])
+        cache = {"k": junk.at[:, :s - 1].set(k[:, :s - 1]),
+                 "v": junk.at[:, :s - 1].set(v[:, :s - 1])}
+        step, new = L.attn_decode(p, cfg, x[:, -1:], cache,
+                                  jnp.int32(s - 1), None)
+        np.testing.assert_array_equal(np.asarray(new["k"][:, s - 1]),
+                                      np.asarray(k[:, s - 1]))
+        np.testing.assert_allclose(np.asarray(pre[:, -1]),
+                                   np.asarray(step[:, 0]),
                                    atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("join", [False, True])
+    def test_serve_tick_holds_no_loop(self, join):
+        """The decode attention of a flash preset's compiled serve tick is
+        straight-line code: no ``while`` in the lowered program, with or
+        without a join bundle (a blockwise scan there ran as one device
+        loop per layer)."""
+        from repro.core.buffers import StreamBuffer
+        from repro.launch import model_serve as ms
+        ps = ms.serve_pipeline(model="stablelm-smoke-flash", slots=4,
+                               max_seq=64)
+        ps.realize()
+        elem = ps.elements["lm"]
+        params = jax.eval_shape(ps.init, jax.random.PRNGKey(0))
+        state = jax.eval_shape(ps.init_state)
+        bundle = (StreamBuffer(tensors=elem._zero_admit(), meta={})
+                  if join else elem.empty_admit())
+        src = ps.plan.query_sources[0].name
+        tick = ps.plan.compiled_serve_tick(state)
+        text = tick.lower(params, state, {src: bundle}).as_text()
+        assert "while" not in text
