@@ -920,11 +920,13 @@ class StreamingQueryBatcher(QueryBatcher):
                 outputs, run.state = serve(run.params, run.state,
                                            {src: bundle})
             with tracing.span("lm.decode.fetch"):
-                toks, emitted, finished = jax.device_get(
+                toks, emitted, finished, *load = jax.device_get(
                     outputs[sink].tensors)
             self.decode_ticks += 1
             run.frames += 1
             n_active = int(emitted.sum())
+            if load:
+                sp.set(**elem.note_routes(load[0], n_active, tick=True))
             self.batched_frames += n_active
             if n_active > 1:
                 self.batches += 1
@@ -1021,6 +1023,7 @@ class StreamingQueryBatcher(QueryBatcher):
             "admit_d2h_bytes": self.admit_d2h_bytes,
             "admit_h2d_bytes": self.admit_h2d_bytes,
         })
+        base.update(self._serve_elem().moe_counters)
         return base
 
 

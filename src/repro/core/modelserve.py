@@ -16,7 +16,11 @@ program a per-request sequential decode runs, each slot an independent row
 of every batched matmul — and commits state with a ``where(active, new,
 old)`` select, so continuous-batched output is bitwise the sequential
 output regardless of join/leave order (pinned in
-tests/test_model_serving.py).
+tests/test_model_serving.py).  A model with routed experts is the one
+exception to slot independence inside the tick: its expert layers
+(``models/moe.py`` ``moe_serve``) take the vmapped slot batch as one group
+of tokens, so each held expert runs once per tick for the slots routed to
+it; each token's own rows are still computed alone.
 
 The host half (prefill, admit-bundle assembly) lives on the element too:
 the StreamingQueryBatcher calls ``host_prefill`` when a request arrives,
@@ -90,7 +94,9 @@ class ModelServeElement(Element):
       or the structurally tiny ``(mask,)`` + ``meta={"empty": True}`` on a
       no-join tick (static aux — its own cached trace, no cache transfer)
     Output frame (captured at the serversink):
-      ``(token[S], emitted[S], finished[S])``
+      ``(token[S], emitted[S], finished[S])``, and for a model with experts
+      ``load[n_moe_layers, E_held]``: the tick's tokens routed to each
+      expert this chip holds, per expert layer (``note_routes`` counts it)
     """
 
     #: streaming serve workload: ExecutionPlan routes this pipeline through
@@ -106,6 +112,8 @@ class ModelServeElement(Element):
         self.max_seq = int(props.get("max_seq", max_seq))
         self._cfg = None
         self._prefill_jit = None
+        #: routed-expert counters of a model with experts (``note_routes``)
+        self.moe_counters: Dict[str, int] = {}
 
     # -- config / cache templates (host-side, cached) -------------------------
     @property
@@ -186,14 +194,23 @@ class ModelServeElement(Element):
         #    independent rows of every batched matmul — bitwise parity is
         #    pinned in tests/test_model_serving.py).  Inactive slots compute
         #    on zero caches and are discarded by the select below.
+        #    A model with experts routes the whole slot batch as one group
+        #    (``moe_serve``'s vmap rule) and counts the held experts' load.
         def slot_step(c, tok, act):
-            logits, new_c = transformer.lm_decode(params, cfg, tok[None], c)
+            if cfg.n_experts:
+                logits, new_c, routes = transformer.lm_decode(
+                    params, cfg, tok[None], c, with_routes=True)
+                load = self._held_load(routes) * act.astype(jnp.int32)
+            else:
+                logits, new_c = transformer.lm_decode(params, cfg, tok[None],
+                                                      c)
+                load = None
             new_tok = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
             c_out = jax.tree_util.tree_map(
                 lambda old, new: jnp.where(act, new, old), c, new_c)
-            return c_out, jnp.where(act, new_tok, tok)
+            return c_out, jnp.where(act, new_tok, tok), load
 
-        cache, token = jax.vmap(slot_step)(cache, token, active)
+        cache, token, load = jax.vmap(slot_step)(cache, token, active)
 
         # 3. retire: a slot leaves the batch the tick its budget hits zero
         emitted = active
@@ -205,7 +222,37 @@ class ModelServeElement(Element):
             "token": token,
             "remaining": jnp.maximum(rem_after, 0),
         })
-        return [inputs[0].with_(tensors=(token, emitted, finished), meta={})]
+        lanes = (token, emitted, finished)
+        if load is not None:
+            lanes += (jnp.sum(load, axis=0),)
+        return [inputs[0].with_(tensors=lanes, meta={})]
+
+    # -- routed experts -------------------------------------------------------
+    def _held_load(self, routes):
+        """routes [n_moe, ..., k] (global expert ids) -> tokens routed to
+        each held expert, [n_moe, E_held] int32."""
+        held = self.cfg.n_experts_held
+        hit = jax.nn.one_hot(routes.reshape(routes.shape[0], -1), held,
+                             dtype=jnp.int32)
+        return jnp.sum(hit, axis=1)
+
+    def note_routes(self, load, tokens: int, tick: bool) -> Dict[str, int]:
+        """Add one prefill's or decode tick's routing to ``moe_counters``:
+        ``moe_routed_held`` (assignments to held experts), ``moe_routed_total``
+        (all assignments: tokens x top_k x expert layers) and, over decode
+        ticks, ``moe_load_max`` (the most tokens one held expert took in one
+        layer of one tick).  Returns this call's three numbers."""
+        load = np.asarray(load)
+        mine = {"moe_routed_held": int(load.sum()),
+                "moe_routed_total": tokens * self.cfg.top_k * load.shape[0],
+                "moe_load_max": int(load.max()) if load.size else 0}
+        c = self.moe_counters
+        for key in ("moe_routed_held", "moe_routed_total"):
+            c[key] = c.get(key, 0) + mine[key]
+        c.setdefault("moe_load_max", 0)
+        if tick:
+            c["moe_load_max"] = max(c["moe_load_max"], mine["moe_load_max"])
+        return mine
 
     # -- host half (StreamingQueryBatcher calls) ------------------------------
     def active_slots(self, state) -> int:
@@ -238,14 +285,24 @@ class ModelServeElement(Element):
 
             @jax.named_scope("lm_prefill")
             def prefill(p, toks):
-                logits, cache = transformer.lm_prefill(p, cfg, toks[None],
-                                                       max_seq)
-                return jnp.argmax(logits[0], axis=-1).astype(jnp.int32), cache
+                if cfg.n_experts:
+                    logits, cache, routes = transformer.lm_prefill(
+                        p, cfg, toks[None], max_seq, with_routes=True)
+                    load = self._held_load(routes)
+                else:
+                    logits, cache = transformer.lm_prefill(p, cfg, toks[None],
+                                                           max_seq)
+                    load = None
+                tok = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
+                return tok, cache, load
             self._prefill_jit = jax.jit(prefill)
         with tracing.span("lm.prefill") as sp:
             sp.set(n=len(prompt))
-            tok, cache = self._prefill_jit(params,
-                                           jnp.asarray(prompt, jnp.int32))
+            tok, cache, load = self._prefill_jit(
+                params, jnp.asarray(prompt, jnp.int32))
+            if load is not None:
+                tok, load = jax.device_get((tok, load))
+                sp.set(**self.note_routes(load, len(prompt), tick=False))
             return int(tok), cache
 
     def empty_admit(self) -> StreamBuffer:

@@ -58,7 +58,30 @@ def _stablelm_1_6b() -> ModelConfig:
     return dataclasses.replace(stablelm_1_6b.config(), use_flash_attn=True)
 
 
+def _deepseek_v2_ep8() -> ModelConfig:
+    """deepseek-v2-236b as one chip of an 8-way expert-parallel deployment:
+    every width as published (MLA with YaRN, 160-way group-limited router,
+    top-6, 2 shared experts, dense first layer, bf16), 20 of each layer's
+    160 routed experts held here, and 5 of the 60 layers (the dense one and
+    four expert layers; the rest would be further pipeline stages)."""
+    from ..configs import deepseek_v2_236b
+    return dataclasses.replace(deepseek_v2_236b.config(), n_layers=5,
+                               experts_held=20)
+
+
+def _deepseek_v2_smoke() -> ModelConfig:
+    """Small deepseek-v2 for the CPU: 3 layers (dense, then two expert
+    layers), 16 experts in 4 groups with the best 2 kept, top-4, 8 held
+    here, the published routing and YaRN settings, float32."""
+    from ..configs import deepseek_v2_236b
+    return dataclasses.replace(deepseek_v2_236b.config().smoke(), n_layers=3,
+                               n_experts=16, n_group=4, topk_group=2, top_k=4,
+                               experts_held=8, n_shared_experts=2)
+
+
 register_serve_model("stablelm-1.6b", _stablelm_1_6b)
+register_serve_model("deepseek-v2-236b-ep8", _deepseek_v2_ep8)
+register_serve_model("deepseek-v2-smoke", _deepseek_v2_smoke)
 register_serve_model("stablelm-smoke-flash", _stablelm_smoke_flash)
 register_serve_model("stablelm-smoke", _stablelm_smoke)
 register_serve_model("recurrentgemma-smoke", _recurrentgemma_smoke)

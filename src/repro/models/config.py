@@ -42,8 +42,15 @@ class ModelConfig:
     n_shared_experts: int = 0
     d_ff_expert: Optional[int] = None
     first_dense: int = 0                # leading dense layers (deepseek: 1)
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25       # training dispatch only; serving
+                                        # (prefill, decode) drops no token
     router_aux_weight: float = 0.01
+    n_group: int = 1                    # group-limited routing (deepseek-v2:
+    topk_group: int = 1                 # 8 groups, the best 3 kept)
+    norm_topk_prob: bool = True         # renormalise the top-k weights
+    routed_scaling_factor: float = 1.0  # applied where not renormalised
+    experts_held: int = 0               # routed experts this chip holds of
+                                        # each layer (0: all n_experts)
 
     # MLA (deepseek-v2)
     mla: bool = False
@@ -52,6 +59,13 @@ class ModelConfig:
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+    # YaRN rotary scaling of the rope dims (deepseek-v2); factor 1: plain
+    yarn_factor: float = 1.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # SSM (mamba2 SSD)
     ssm_state: int = 0
@@ -107,6 +121,10 @@ class ModelConfig:
         return self.n_experts > 0 and layer >= self.first_dense
 
     @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
     def attention_free(self) -> bool:
         return all(k in ("S", "R") for k in self.layer_pattern)
 
@@ -118,8 +136,13 @@ class ModelConfig:
 
     def smoke(self) -> "ModelConfig":
         """Reduced variant of the same family: <=2 layers (pattern-preserving),
-        d_model<=512, <=4 experts — runs a real step on CPU."""
+        d_model<=512, <=4 experts — runs a real step on CPU.  Routing groups
+        still divide the experts and no more groups are kept than exist."""
         pat = self.layer_pattern
+        n_experts = min(self.n_experts, 4)
+        n_group = min(self.n_group, max(n_experts, 1))
+        while n_experts % n_group:
+            n_group -= 1
         n_layers = max(2, min(len(pat), 3)) if len(pat) > 1 else 2
         d_model = min(self.d_model, 256)
         n_heads = min(self.n_heads, 4)
@@ -134,8 +157,11 @@ class ModelConfig:
             head_dim=hd,
             d_ff=min(self.d_ff, 512) if self.d_ff else 0,
             vocab=min(self.vocab, 512),
-            n_experts=min(self.n_experts, 4),
+            n_experts=n_experts,
             top_k=min(self.top_k, 2),
+            n_group=n_group,
+            topk_group=min(self.topk_group, n_group),
+            experts_held=min(self.experts_held, n_experts),
             n_shared_experts=min(self.n_shared_experts, 1),
             d_ff_expert=min(self.d_ff_expert, 128) if self.d_ff_expert else None,
             first_dense=min(self.first_dense, 1),

@@ -61,16 +61,22 @@ def rope_freqs(head_dim: int, rope_frac: float, theta: float) -> jnp.ndarray:
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, rope_frac: float,
-               theta: float) -> jnp.ndarray:
+               theta: float, inv_freq: Optional[jnp.ndarray] = None,
+               mscale: float = 1.0) -> jnp.ndarray:
     """x: [B, S, H, hd]; positions: [B, S] (absolute).  Rotates the leading
-    rope_frac fraction of hd (partial rotary, stablelm-style)."""
+    rope_frac fraction of hd (partial rotary, stablelm-style).  ``inv_freq``
+    replaces the plain ``theta`` frequencies (YaRN), ``mscale`` scales the
+    rotation's cos and sin."""
     hd = x.shape[-1]
     rot = int(hd * rope_frac) // 2 * 2
     if rot == 0:
         return x
-    freqs = rope_freqs(hd, rope_frac, theta)                       # [rot/2]
+    freqs = rope_freqs(hd, rope_frac, theta) if inv_freq is None \
+        else inv_freq                                              # [rot/2]
     ang = positions[..., None].astype(jnp.float32) * freqs         # [B,S,rot/2]
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     xr, xp = x[..., :rot], x[..., rot:]
     x1, x2 = xr[..., 0::2], xr[..., 1::2]
     y1 = x1 * cos - x2 * sin

@@ -1,19 +1,36 @@
-"""Mixture-of-Experts layer: top-k router + capacity-bounded gather dispatch
-+ optional shared experts (DeepSeek-V2) — expert-parallel over the `model`
-mesh axis.
+"""Mixture-of-Experts layer: router + expert dispatch + optional shared
+experts (DeepSeek-V2), on three paths that share one router (``route``).
 
-Dispatch avoids the GShard one-hot einsum (whose FLOPs, T·E·C·d, would dwarf
-the expert FLOPs at 160 experts) in favour of sort+gather: tokens are
-argsorted by expert id, each expert gathers its first C tokens, computes the
-gated FF, and results scatter-add back weighted by router probs.  Gathers
-are bandwidth, not FLOPs, so HLO_FLOPs stays close to 6·N_active·D.
+* ``moe_serve`` — prefill and decode: dropless.  Every token reaches each of
+  its top-k experts that this chip holds (``experts_held`` of ``n_experts``,
+  a contiguous share starting at ``first``); the router still scores all
+  ``n_experts``, and absent experts' parts are left out, as on one chip of an
+  expert-parallel deployment.  The held experts run as one grouped dispatch:
+  assignments sorted by expert, cut into blocks of rows of one expert each,
+  and a loop over the blocks that exist, so the work follows the routed
+  assignments rather than tokens x held experts.  Under ``jax.vmap`` (the
+  serve tick's per-slot map) a ``custom_vmap`` rule treats the whole slot
+  batch as one group of tokens.
+* ``_apply_moe_dense`` — training on one device: capacity-bounded sort +
+  gather (each expert takes its first ``capacity_factor`` share of tokens);
+  gathers are bandwidth, not FLOPs, so HLO_FLOPs stays close to 6·N_active·D.
+* ``_apply_moe_shard_map`` — the production mesh: expert-parallel over the
+  ``model`` axis, capacity-bounded like the dense path.
+
+Routing (``route``): softmax scores in float32; with ``n_group > 1`` the
+group-limited greedy selection (a group scores its best expert, the best
+``topk_group`` groups stay, the top-k is taken among their experts); the
+top-k weights renormalised (``norm_topk_prob``) or else scaled by
+``routed_scaling_factor``.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
 
 from .config import ModelConfig
 from .layers import dense_init, _act
@@ -23,11 +40,11 @@ from .sharding import shard
 def moe_init(rng, cfg: ModelConfig) -> Dict:
     d = cfg.d_model
     f = cfg.d_ff_expert or cfg.d_ff
-    e = cfg.n_experts
+    e = cfg.n_experts_held
     dt = jnp.dtype(cfg.dtype)
     ks = jax.random.split(rng, 5)
     p = {
-        "router": dense_init(ks[0], d, e, jnp.float32, scale=0.02),
+        "router": dense_init(ks[0], d, cfg.n_experts, jnp.float32, scale=0.02),
         "w_up": (jax.random.normal(ks[1], (e, d, f)) * d ** -0.5).astype(dt),
         "w_gate": (jax.random.normal(ks[2], (e, d, f)) * d ** -0.5).astype(dt),
         "w_down": (jax.random.normal(ks[3], (e, f, d)) * f ** -0.5).astype(dt),
@@ -41,6 +58,122 @@ def moe_init(rng, cfg: ModelConfig) -> Dict:
             "w_down": dense_init(k3, fs, d, dt),
         }
     return p
+
+
+def route(router: jnp.ndarray, cfg: ModelConfig, xt: jnp.ndarray
+          ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """xt [T, d] -> (weights [T, k] f32, experts [T, k] int32, probs [T, E]).
+    Experts are global ids in ``[0, n_experts)``."""
+    t = xt.shape[0]
+    e, k = cfg.n_experts, cfg.top_k
+    logits = xt.astype(jnp.float32) @ router.astype(jnp.float32)   # [T, E]
+    probs = jax.nn.softmax(logits, axis=-1)
+    scores = probs
+    if cfg.n_group > 1 and cfg.topk_group < cfg.n_group:
+        g = cfg.n_group
+        best = jnp.max(probs.reshape(t, g, e // g), axis=-1)        # [T, G]
+        _, top_groups = jax.lax.top_k(best, cfg.topk_group)
+        keep = jnp.any(top_groups[:, :, None] == jnp.arange(g), axis=1)
+        scores = jnp.where(jnp.repeat(keep, e // g, axis=1), probs, 0.0)
+    topw, topi = jax.lax.top_k(scores, k)                           # [T, k]
+    if cfg.norm_topk_prob:
+        topw = topw / jnp.sum(topw, -1, keepdims=True)
+    else:
+        topw = topw * cfg.routed_scaling_factor
+    return topw, topi.astype(jnp.int32), probs
+
+
+def block_rows(cfg: ModelConfig, tokens: int) -> int:
+    """Rows of one block of the grouped dispatch: a multiple of 8 near the
+    mean assignments per expert (8 at decode, ~40 for a 1k prefill)."""
+    mean = -(-tokens * cfg.top_k // max(cfg.n_experts, 1))
+    return max(8, min(256, -(-mean // 8) * 8))
+
+
+def _held_experts(p: Dict, cfg: ModelConfig, xt, topw, topi, first: int):
+    """Routed part of the held experts ``[first, first + E_held)``: every
+    assignment to them computed once (dropless), float32 [T, d]."""
+    t, d = xt.shape
+    k = cfg.top_k
+    e_held = p["w_up"].shape[0]
+    n = t * k
+    bm = block_rows(cfg, t)
+    local = topi.reshape(n) - first
+    key = jnp.where((local >= 0) & (local < e_held), local, e_held)
+    order = jnp.argsort(key, stable=True)           # assignments by expert
+    counts = jnp.bincount(key, length=e_held + 1)[:e_held]
+    row_start = jnp.cumsum(counts) - counts
+    blocks = (counts + bm - 1) // bm                # blocks of each expert
+    blk_end = jnp.cumsum(blocks)
+    weights = topw.reshape(n)
+
+    def block(b, acc):
+        e = jnp.sum(blk_end <= b)                   # the block's expert
+        r = (b - (blk_end[e] - blocks[e])) * bm + jnp.arange(bm)
+        valid = r < counts[e]
+        a = order[jnp.clip(row_start[e] + r, 0, n - 1)]
+        tok = a // k
+        xb = jnp.take(xt, tok, axis=0)
+        h = _act(cfg, xb @ p["w_gate"][e]) * (xb @ p["w_up"][e])
+        yb = (h @ p["w_down"][e]).astype(jnp.float32)
+        yb = yb * jnp.where(valid, weights[a], 0.0)[:, None]
+        return acc.at[tok].add(yb)
+    return jax.lax.fori_loop(0, blk_end[-1], block,
+                             jnp.zeros((t, d), jnp.float32))
+
+
+def _shared_experts(p: Dict, cfg: ModelConfig, xt):
+    sp = p["shared"]
+    h = _act(cfg, xt @ sp["w_gate"]) * (xt @ sp["w_up"])
+    return (h @ sp["w_down"]).astype(jnp.float32)
+
+
+def moe_tokens(p: Dict, cfg: ModelConfig, xt: jnp.ndarray, first: int = 0,
+               shared: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The serving layer on one group of tokens: xt [T, d] -> (y [T, d],
+    routed experts [T, k]).  ``shared=False`` leaves the shared experts out
+    (a share's routed part alone)."""
+    with jax.named_scope("moe_route"):
+        topw, topi, _ = route(p["router"], cfg, xt)
+    with jax.named_scope("moe_experts"):
+        y = _held_experts(p, cfg, xt, topw, topi, first)
+    if shared and "shared" in p:
+        y = y + _shared_experts(p, cfg, xt)
+    return y.astype(xt.dtype), topi
+
+
+@functools.lru_cache(maxsize=None)
+def _serve_fn(cfg: ModelConfig, first: int):
+    def plain(p, x):
+        y, topi = moe_tokens(p, cfg, x.reshape(-1, x.shape[-1]), first)
+        return y.reshape(x.shape), topi.reshape(x.shape[:-1] + (cfg.top_k,))
+
+    fn = custom_vmap(plain)
+
+    @fn.def_vmap
+    def _grouped(axis_size, in_batched, p, x):
+        p_batched, x_batched = in_batched
+        if x_batched and not any(jax.tree_util.tree_leaves(p_batched)):
+            # one set of weights over the batch: the batch's tokens are one
+            # group, so each held expert runs once for all of them
+            return plain(p, x), (True, True)
+        axes = (jax.tree_util.tree_map(lambda b: 0 if b else None,
+                                       p_batched), 0 if x_batched else None)
+        return jax.vmap(plain, in_axes=axes, axis_size=axis_size)(p, x), \
+            (True, True)
+    return fn
+
+
+def moe_serve(p: Dict, cfg: ModelConfig, x: jnp.ndarray, first: int = 0
+              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Prefill and decode: x [B, S, d] -> (y [B, S, d], routed experts
+    [B, S, k]).  On a production mesh this is ``apply_moe``'s expert-parallel
+    path (capacity-bounded), which returns no routes."""
+    from .sharding import current_rules
+    mesh = current_rules().get("__mesh__")
+    if mesh is not None and "model" in getattr(mesh, "axis_names", ()):
+        return apply_moe(p, cfg, x)
+    return _serve_fn(cfg, first)(p, x)
 
 
 def apply_moe(p: Dict, cfg: ModelConfig, x: jnp.ndarray
@@ -62,12 +195,12 @@ def _apply_moe_dense(p: Dict, cfg: ModelConfig, x: jnp.ndarray
     b, s, d = x.shape
     t = b * s
     e, k = cfg.n_experts, cfg.top_k
+    if cfg.n_experts_held != e:
+        raise NotImplementedError("training computes every expert; "
+                                  "experts_held is a serving share")
     xt = x.reshape(t, d)
 
-    logits = (xt.astype(jnp.float32) @ p["router"])            # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    topw, topi = jax.lax.top_k(probs, k)                        # [T, k]
-    topw = topw / jnp.sum(topw, -1, keepdims=True)
+    topw, topi, probs = route(p["router"], cfg, xt)             # [T, k]
 
     # ---- load-balance aux (Switch): E * Σ_e fraction_e * prob_e ----
     onehot_count = jnp.sum(jax.nn.one_hot(topi, e, dtype=jnp.float32), axis=1)
@@ -158,10 +291,7 @@ def _apply_moe_shard_map(p: Dict, cfg: ModelConfig, x: jnp.ndarray, mesh
         bl, sl, _ = xl.shape
         t = bl * sl
         xt = xl.reshape(t, d)
-        logits = xt.astype(jnp.float32) @ router              # [T_loc, E]
-        probs = jax.nn.softmax(logits, axis=-1)
-        topw, topi = jax.lax.top_k(probs, k)
-        topw = topw / jnp.sum(topw, -1, keepdims=True)
+        topw, topi, probs = route(router, cfg, xt)
 
         onehot_count = jnp.sum(jax.nn.one_hot(topi, e, dtype=jnp.float32), 1)
         frac = jnp.mean(onehot_count, axis=0)

@@ -131,10 +131,14 @@ def _mixer_train(p, cfg: ModelConfig, kind: str, x):
     raise ValueError(kind)
 
 
-def _mlp_part(p, cfg: ModelConfig, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def _mlp_part(p, cfg: ModelConfig, x, serve: bool = False
+              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """-> (x + mlp(norm(x)), aux).  aux is the router's balance loss in
+    training; on the serve path (``serve``: dropless experts) it is the
+    experts each token was routed to, [B, S, k]."""
     h = L.apply_norm(p["norm2"], x, cfg)
     if "moe" in p:
-        y, aux = MOE.apply_moe(p["moe"], cfg, h)
+        y, aux = (MOE.moe_serve if serve else MOE.apply_moe)(p["moe"], cfg, h)
     else:
         y, aux = L.apply_mlp(p["mlp"], cfg, h), jnp.float32(0)
     return x + y, aux
@@ -150,7 +154,8 @@ def block_train(p, cfg: ModelConfig, kind: str, x) -> Tuple[jnp.ndarray, jnp.nda
 
 def block_prefill(p, cfg: ModelConfig, kind: str, x, max_seq: int
                   ) -> Tuple[jnp.ndarray, Dict, jnp.ndarray]:
-    """Train-mode forward that also emits this layer's decode cache."""
+    """Prefill forward that also emits this layer's decode cache; the
+    third value is ``_mlp_part``'s serve-path aux."""
     b, s, _ = x.shape
     pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     h = L.apply_norm(p["norm1"], x, cfg)
@@ -186,11 +191,14 @@ def block_prefill(p, cfg: ModelConfig, kind: str, x, max_seq: int
     x = x + y
     if kind == "S":
         return x, cache, jnp.float32(0)
-    x, aux = _mlp_part(p, cfg, x)
+    x, aux = _mlp_part(p, cfg, x, serve=True)
     return x, cache, aux
 
 
-def block_decode(p, cfg: ModelConfig, kind: str, x, cache, pos):
+def block_decode(p, cfg: ModelConfig, kind: str, x, cache, pos,
+                 with_routes: bool = False):
+    """One token through one layer; ``with_routes`` adds the experts it was
+    routed to ([B, 1, k], None for a layer without experts)."""
     h = L.apply_norm(p["norm1"], x, cfg)
     if kind in ("G", "L"):
         window = cfg.window if kind == "L" else None
@@ -205,10 +213,11 @@ def block_decode(p, cfg: ModelConfig, kind: str, x, cache, pos):
     else:
         raise ValueError(kind)
     x = x + y
-    if kind == "S":
-        return x, new_cache
-    x, _ = _mlp_part(p, cfg, x)
-    return x, new_cache
+    routes = None
+    if kind != "S":
+        x, aux = _mlp_part(p, cfg, x, serve=True)
+        routes = aux if "moe" in p else None
+    return (x, new_cache, routes) if with_routes else (x, new_cache)
 
 
 # ---------------------------------------------------------------------------
@@ -275,33 +284,52 @@ def lm_train(params, cfg: ModelConfig, tokens,
     return L.unembed(params["embed"], cfg, h), aux
 
 
-def lm_decode(params, cfg: ModelConfig, token, cache) -> Tuple[jnp.ndarray, Dict]:
+def lm_decode(params, cfg: ModelConfig, token, cache,
+              with_routes: bool = False):
+    """-> (logits [B, V], cache); ``with_routes`` (a model with expert
+    layers) adds the experts each token was routed to in each expert
+    layer, [n_moe_layers, B, k]."""
     pos = cache["pos"]
     x = L.embed(params["embed"], cfg, token[:, None])
-    new_layers = []
+    new_layers, routes = [], []
     for i, p in enumerate(params["layers"]):
-        x, c = block_decode(p, cfg, cfg.kind(i), x, cache["layers"][i], pos)
+        x, c, r = block_decode(p, cfg, cfg.kind(i), x, cache["layers"][i],
+                               pos, with_routes=True)
         new_layers.append(c)
+        if with_routes and r is not None:
+            routes.append(r[:, 0])
     h = L.apply_norm(params["final_norm"], x, cfg)
     logits = L.unembed(params["embed"], cfg, h)[:, 0]
-    return logits, {"pos": pos + 1, "layers": new_layers}
+    new_cache = {"pos": pos + 1, "layers": new_layers}
+    if with_routes:
+        return logits, new_cache, jnp.stack(routes)
+    return logits, new_cache
 
 
-def lm_prefill(params, cfg: ModelConfig, tokens, max_seq: Optional[int] = None
-               ) -> Tuple[jnp.ndarray, Dict]:
+def lm_prefill(params, cfg: ModelConfig, tokens, max_seq: Optional[int] = None,
+               with_routes: bool = False):
+    """-> (last-position logits [B, V], cache); ``with_routes`` adds the
+    experts each position was routed to, [n_moe_layers, B, S, k]."""
     x = L.embed(params["embed"], cfg, tokens)
-    return lm_prefill_embedded(params, cfg, x, max_seq or tokens.shape[1])
+    return lm_prefill_embedded(params, cfg, x, max_seq or tokens.shape[1],
+                               with_routes)
 
 
-def lm_prefill_embedded(params, cfg: ModelConfig, x, max_seq: int
-                        ) -> Tuple[jnp.ndarray, Dict]:
+def lm_prefill_embedded(params, cfg: ModelConfig, x, max_seq: int,
+                        with_routes: bool = False):
     caches: List[Dict] = []
+    routes = []
     for i, p in enumerate(params["layers"]):
-        x, cache, _ = block_prefill(p, cfg, cfg.kind(i), x, max_seq)
+        x, cache, aux = block_prefill(p, cfg, cfg.kind(i), x, max_seq)
         caches.append(cache)
+        if with_routes and "moe" in p:
+            routes.append(aux)
     hfin = L.apply_norm(params["final_norm"], x, cfg)
     logits = L.unembed(params["embed"], cfg, hfin[:, -1:])[:, 0]
-    return logits, {"pos": jnp.int32(x.shape[1]), "layers": caches}
+    cache = {"pos": jnp.int32(x.shape[1]), "layers": caches}
+    if with_routes:
+        return logits, cache, jnp.stack(routes)
+    return logits, cache
 
 
 # ---------------------------------------------------------------------------

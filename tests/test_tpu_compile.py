@@ -6,7 +6,8 @@ JAX, compiles for a described ``v5e:2x2`` topology, so these tests catch
 what interpret mode cannot — block shapes off the (8, 128) tiling, too much
 fast memory, a program that does not fit 16 GB — at the widths the chip
 serves: stablelm-1.6b (32 heads × 64, d 2048, 24 layers, bf16) and the
-paper's VGA frames (480 × 640 × 3) on the codec path.
+paper's VGA frames (480 × 640 × 3) on the codec path, and deepseek-v2's
+share of one expert-parallel chip (latent attention, 20 of 160 experts).
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -198,6 +199,68 @@ def test_full_width_join_tick_fits_one_chip(one_chip, stablelm_server):
     live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert live < V5E_HBM, f"join tick needs {live / 1e9:.2f} GB"
+
+
+@pytest.fixture(scope="module")
+def deepseek_server():
+    from repro.launch import model_serve as ms
+    ps = ms.serve_pipeline(model="deepseek-v2-236b-ep8", slots=64,
+                           max_seq=2048)
+    ps.realize()
+    return ps
+
+
+@pytest.mark.parametrize("join", [False, True], ids=["nojoin", "join"])
+def test_deepseek_tick_fits_one_chip(one_chip, deepseek_server, join):
+    """deepseek-v2's share of one EP8 chip (5 layers, 20 of 160 experts
+    held, bf16) serving 64 slots x 2048 latent positions: the no-join tick
+    and the tick where every slot joins fit 16 GB."""
+    ps = deepseek_server
+    elem = ps.elements["lm"]
+    params = _on(one_chip, jax.eval_shape(ps.init, jax.random.PRNGKey(0)))
+    state = _on(one_chip, jax.eval_shape(ps.init_state))
+    s = elem.slots
+    if join:
+        lanes = [jax.ShapeDtypeStruct((s,), dt) for dt in
+                 (jnp.bool_, jnp.int32, jnp.int32)]
+        caches = [jax.ShapeDtypeStruct((s,) + l.shape, l.dtype) for l in
+                  jax.tree_util.tree_leaves(
+                      jax.eval_shape(elem._cache_template))]
+        admit = StreamBuffer(tensors=(*lanes, *caches), meta={})
+    else:
+        admit = StreamBuffer(tensors=(jax.ShapeDtypeStruct((s,), jnp.bool_),),
+                             meta={"empty": True})
+    src = ps.plan.query_sources[0].name
+    tick = ps.plan.compiled_serve_tick(state, donate=True)
+    c = tick.lower(params, state, {src: _on(one_chip, admit)}).compile()
+    mem = c.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert live < V5E_HBM, f"tick needs {live / 1e9:.2f} GB"
+
+
+def test_deepseek_expert_layer_work_follows_routed_tokens(one_chip):
+    """One expert layer's routed part over the 64-slot batch as the tick
+    maps it (``jax.vmap`` over slots of one token): the compiled program's
+    operations stay within 3x of the work its routed tokens need (64 x 6 x
+    20/160 = 48 assignments to held experts), where running every held
+    expert for every slot would be 27x.  XLA counts the dispatch loop's
+    body once: one block of 8 rows, the loop runs once per block present."""
+    from repro.launch import model_serve as ms
+    from repro.models import moe as MOE
+    cfg = ms.SERVE_MODELS["deepseek-v2-236b-ep8"]()
+    p = jax.eval_shape(lambda k: MOE.moe_init(k, cfg), jax.random.PRNGKey(0))
+    del p["shared"]
+    p = _on(one_chip, p)
+    x = jax.ShapeDtypeStruct((64, 1, 1, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    c = _compile(jax.vmap(lambda p, x: MOE.moe_serve(p, cfg, x),
+                          in_axes=(None, 0)), p, x)
+    cost = c.cost_analysis()
+    flops = (cost[0] if isinstance(cost, list) else cost)["flops"]
+    expert = 2 * 3 * cfg.d_model * cfg.d_ff_expert
+    routed = expert * 64 * cfg.top_k * cfg.experts_held // cfg.n_experts
+    assert 8 * expert <= flops <= 3 * routed, (flops, routed)
 
 
 def test_mesh_sharded_burst_compiles_for_four_chips(topo):
