@@ -695,8 +695,9 @@ class StreamingQueryBatcher(QueryBatcher):
        the stream for a slot.  ``gen <= 1`` answers immediately.
     2. **decode tick** — at most ONCE per scheduler tick (``tick_source``
        guard; the drain loop flushes many times per tick): assign free
-       slots to waiting streams lowest-slot-first, assemble the admit
-       bundle, and run ONE ``compiled_serve_tick`` dispatch over the whole
+       slots to waiting streams lowest-slot-first, write the joiners'
+       caches into their slot rows on the device, assemble the admit
+       lanes, and run ONE ``compiled_serve_tick`` dispatch over the whole
        slot table.  Joins and leaves are data (admit mask / finished lane),
        never a retrace.
     3. **finish** — slots whose ``finished`` lane fired deliver their
@@ -744,10 +745,16 @@ class StreamingQueryBatcher(QueryBatcher):
         self.join_ticks = 0
         #: occupied slots summed over decode ticks
         self.slot_ticks = 0
-        #: bytes of the joiners' prefilled caches fetched to the host
+        #: bytes fetched to the host on join ticks (none: the joiners'
+        #: caches stay on the device)
         self.admit_d2h_bytes = 0
-        #: bytes of the admit bundles shipped to the device on join ticks
+        #: bytes shipped to the device on join ticks: the admit lanes, and
+        #: any joiner cache handed over host-resident
         self.admit_h2d_bytes = 0
+        #: slot rows the joiners' caches were written into on the device
+        self.admit_rows = 0
+        #: bytes of the joiners' caches written into their slot rows
+        self.admit_write_bytes = 0
 
     # -- introspection ---------------------------------------------------------
     def in_flight(self, client_id: int) -> bool:
@@ -908,14 +915,25 @@ class StreamingQueryBatcher(QueryBatcher):
             serve = plan.compiled_serve_tick(run.state)
             bundle = elem.build_admit(admits)
             if admits:
-                # counted from static shapes: the joiners' caches the admit
-                # build fetched and the bundle the serve call ships
-                fetched = _nbytes([cache for *_, cache in admits])
-                shipped = _nbytes(bundle.tensors)
+                with tracing.span("lm.admit.write") as wsp:
+                    wsp.set(n=len(admits))
+                    run.state = {**run.state, elem.name: elem.write_rows(
+                        run.state[elem.name], admits)}
+                # counted from static shapes: the joiners' caches written
+                # into their slot rows on the device (nothing fetched), and
+                # what the host ships: the lanes and any host-resident cache
+                caches = [cache for *_, cache in admits]
+                written = _nbytes(caches)
+                shipped = _nbytes(bundle.tensors) + sum(
+                    int(leaf.nbytes)
+                    for leaf in jax.tree_util.tree_leaves(caches)
+                    if isinstance(leaf, np.ndarray))
                 self.join_ticks += 1
-                self.admit_d2h_bytes += fetched
                 self.admit_h2d_bytes += shipped
-                sp.set(d2h_bytes=fetched, h2d_bytes=shipped)
+                self.admit_rows += len(admits)
+                self.admit_write_bytes += written
+                sp.set(d2h_bytes=0, h2d_bytes=shipped,
+                       admit_rows=len(admits), admit_write_bytes=written)
             with tracing.span("lm.decode.dispatch"):
                 outputs, run.state = serve(run.params, run.state,
                                            {src: bundle})
@@ -1022,6 +1040,8 @@ class StreamingQueryBatcher(QueryBatcher):
             "slot_ticks": self.slot_ticks,
             "admit_d2h_bytes": self.admit_d2h_bytes,
             "admit_h2d_bytes": self.admit_h2d_bytes,
+            "admit_rows": self.admit_rows,
+            "admit_write_bytes": self.admit_write_bytes,
         })
         base.update(self._serve_elem().moe_counters)
         return base

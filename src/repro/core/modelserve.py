@@ -5,10 +5,11 @@ hybrid, ...) behind ``tensor_query_serversrc ! model_serve !
 tensor_query_serversink``.  Decode state is PLAN STATE: a slot-stacked
 KV-cache / recurrent-state pytree plus an active-slot mask, carried across
 ticks through the pipeline state dict.  Continuous batching happens INSIDE
-one jitted decode dispatch — requests join (slot allocation, prefilled
-cache merged in under the admit mask) and leave (slots freed when
-``remaining`` hits zero) mid-generation without retracing, because the
-traced program only sees fixed slot-axis shapes (DESIGN.md §7).
+one jitted decode dispatch — requests join (slot allocation: the
+prefilled cache written into its slot row on the device, its lanes merged
+in under the admit mask) and leave (slots freed when ``remaining`` hits
+zero) mid-generation without retracing, because the traced programs only
+see fixed slot-axis shapes (DESIGN.md §7).
 
 Parity-by-construction: the decode tick runs each slot as an independent
 ``b=1`` ``lm_decode`` via ``jax.vmap`` over the slot axis — the same traced
@@ -22,10 +23,11 @@ exception to slot independence inside the tick: its expert layers
 of tokens, so each held expert runs once per tick for the slots routed to
 it; each token's own rows are still computed alone.
 
-The host half (prefill, admit-bundle assembly) lives on the element too:
+The host half (prefill, admit lanes, row writes) lives on the element too:
 the StreamingQueryBatcher calls ``host_prefill`` when a request arrives,
-``build_admit``/``empty_admit`` each tick, and reads the (token, emitted,
-finished) lanes the dispatch captured at the serversink.
+``build_admit``/``empty_admit`` each tick and ``write_rows`` on a tick with
+joiners, and reads the (token, emitted, finished) lanes the dispatch
+captured at the serversink.
 """
 from __future__ import annotations
 
@@ -90,9 +92,10 @@ class ModelServeElement(Element):
       ``remaining[S]``    — decode steps left before the slot frees
 
     Input frame (injected by the batcher at the hoisted serversrc):
-      ``(admit_mask[S], admit_tok[S], admit_rem[S], *admit_cache_leaves)``,
-      or the structurally tiny ``(mask,)`` + ``meta={"empty": True}`` on a
-      no-join tick (static aux — its own cached trace, no cache transfer)
+      ``(admit_mask[S], admit_tok[S], admit_rem[S])`` on a tick with joins
+      (their caches are already in their slot rows: ``write_rows``), or
+      the structurally tiny ``(mask,)`` + ``meta={"empty": True}`` on a
+      no-join tick (static aux — its own cached trace)
     Output frame (captured at the serversink):
       ``(token[S], emitted[S], finished[S])``, and for a model with experts
       ``load[n_moe_layers, E_held]``: the tick's tokens routed to each
@@ -112,6 +115,8 @@ class ModelServeElement(Element):
         self.max_seq = int(props.get("max_seq", max_seq))
         self._cfg = None
         self._prefill_jit = None
+        #: jitted row writes by donation (``row_writer``)
+        self._row_writers: Dict[bool, Callable] = {}
         #: routed-expert counters of a model with experts (``note_routes``)
         self.moe_counters: Dict[str, int] = {}
 
@@ -160,27 +165,19 @@ class ModelServeElement(Element):
         st = ctx.get_state(self.name)
         admit = inputs[0].tensors
 
-        # 1. admit: merge prefilled caches under the admit mask (slot rows
-        #    of leaving/free slots keep their old — soon overwritten —
-        #    values).  A no-join tick carries the STRUCTURALLY tiny empty
-        #    bundle (mask only — ``meta["empty"]`` is static aux), so the
-        #    steady-state decode tick neither ships a zero slot-stacked
-        #    cache over the host edge nor pays the full-state select.
+        # 1. admit: merge the joiners' lanes under the admit mask.  Their
+        #    prefilled caches are already in their slot rows (``write_rows``
+        #    ran before this dispatch).  A no-join tick carries the
+        #    STRUCTURALLY tiny empty bundle (mask only — ``meta["empty"]``
+        #    is static aux), so the steady-state decode tick skips the
+        #    merge.
+        cache = st["cache"]
         if inputs[0].meta.get("empty"):
-            cache, token = st["cache"], st["token"]
+            token = st["token"]
             remaining, active = st["remaining"], st["active"]
         else:
             with jax.named_scope("lm_admit_merge"):
-                treedef = jax.tree_util.tree_structure(self._cache_template())
-                admit_mask, admit_tok, admit_rem = admit[:3]
-                admit_cache = jax.tree_util.tree_unflatten(treedef,
-                                                           list(admit[3:]))
-
-                def merge(old, new):
-                    m = admit_mask.reshape((-1,) + (1,) * (old.ndim - 1))
-                    return jnp.where(m, new, old)
-                cache = jax.tree_util.tree_map(merge, st["cache"],
-                                               admit_cache)
+                admit_mask, admit_tok, admit_rem = admit
                 token = jnp.where(admit_mask, admit_tok, st["token"])
                 remaining = jnp.where(admit_mask, admit_rem,
                                       st["remaining"])
@@ -324,43 +321,58 @@ class ModelServeElement(Element):
         return StreamBuffer(tensors=(self._empty_mask,),
                             meta={"empty": True})
 
-    def _zero_admit(self):
-        """Zero full-width admit rows build_admit scatters into."""
-        if getattr(self, "_zero_admit_base", None) is None:
-            s = self.slots
-            leaves = [np.zeros((s,) + tuple(jnp.shape(l)),
-                               np.dtype(str(l.dtype)))
-                      for l in jax.tree_util.tree_leaves(self._cache_template())]
-            self._zero_admit_base = (
-                np.zeros((s,), np.bool_), np.zeros((s,), np.int32),
-                np.zeros((s,), np.int32), *leaves)
-        return self._zero_admit_base
-
     def build_admit(self, admits) -> StreamBuffer:
-        """Assemble the admit bundle for one tick.  ``admits`` is a list of
-        ``(slot, first_token, remaining, b1_cache)``; rows outside the admit
-        mask are zero (ignored by the masked merge)."""
+        """Assemble the admit lanes for one tick.  ``admits`` is a list of
+        ``(slot, first_token, remaining, b1_cache)``; the bundle carries the
+        joiners' mask, first tokens and budgets over the slot axis (zero
+        outside the mask).  Their caches go into the slot table through
+        ``write_rows``, never through the bundle."""
         if not admits:
             return self.empty_admit()
         with tracing.span("lm.admit.build") as sp:
             sp.set(n=len(admits))
-            with tracing.span("lm.admit.copy"):
-                base = self._zero_admit()
-                mask = base[0].copy()
-                tok = base[1].copy()
-                rem = base[2].copy()
-                leaves = [l.copy() for l in base[3:]]
-            for slot, t, r, cache in admits:
-                with tracing.span("lm.admit.fetch"):
-                    host = jax.device_get(cache)
-                with tracing.span("lm.admit.copy"):
-                    mask[slot] = True
-                    tok[slot] = t
-                    rem[slot] = r
-                    for dst, src in zip(leaves,
-                                        jax.tree_util.tree_leaves(host)):
-                        dst[slot] = src
-        return StreamBuffer(tensors=(mask, tok, rem, *leaves), meta={})
+            s = self.slots
+            mask = np.zeros((s,), np.bool_)
+            tok = np.zeros((s,), np.int32)
+            rem = np.zeros((s,), np.int32)
+            for slot, t, r, _ in admits:
+                mask[slot] = True
+                tok[slot] = t
+                rem[slot] = r
+        return StreamBuffer(tensors=(mask, tok, rem), meta={})
+
+    def write_rows(self, state: dict, admits) -> dict:
+        """Write each joiner's prefilled b=1 cache into its slot row of the
+        slot table, on the device; returns the element state with the rows
+        written.  ``admits`` is ``build_admit``'s list.  A host-resident
+        (numpy) cache is shipped by the call."""
+        write = self.row_writer()
+        cache = state["cache"]
+        for slot, _, _, row in admits:
+            cache = write(cache, np.int32(slot), row)
+        return {**state, "cache": cache}
+
+    def row_writer(self, donate: Optional[bool] = None) -> Callable:
+        """The jitted row write behind ``write_rows``: ``(table, slot,
+        b1_cache) -> table``, one ``dynamic_update_index_in_dim`` per cache
+        leaf with the slot a traced int32 scalar, so one executable serves
+        every joiner and every joiner count, whatever the cache pytree.
+        The table is donated where the plan donates its state
+        (``donate=None``), so the write happens in place and moves one
+        row."""
+        from .plan import ExecutionPlan
+        donate = ExecutionPlan._resolve_donate(donate)
+        fn = self._row_writers.get(donate)
+        if fn is None:
+            def write(table, slot, row):
+                leaves, treedef = jax.tree_util.tree_flatten(table)
+                return jax.tree_util.tree_unflatten(treedef, [
+                    jax.lax.dynamic_update_index_in_dim(t, r.astype(t.dtype),
+                                                        slot, 0)
+                    for t, r in zip(leaves, jax.tree_util.tree_leaves(row))])
+            fn = self._row_writers[donate] = jax.jit(
+                write, donate_argnums=(0,) if donate else ())
+        return fn
 
 
 @register_element("model_serve_stage")

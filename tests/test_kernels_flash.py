@@ -215,7 +215,6 @@ class TestServePathDecodeStep:
         straight-line code: no ``while`` in the lowered program, with or
         without a join bundle (a blockwise scan there ran as one device
         loop per layer)."""
-        from repro.core.buffers import StreamBuffer
         from repro.launch import model_serve as ms
         ps = ms.serve_pipeline(model="stablelm-smoke-flash", slots=4,
                                max_seq=64)
@@ -223,8 +222,8 @@ class TestServePathDecodeStep:
         elem = ps.elements["lm"]
         params = jax.eval_shape(ps.init, jax.random.PRNGKey(0))
         state = jax.eval_shape(ps.init_state)
-        bundle = (StreamBuffer(tensors=elem._zero_admit(), meta={})
-                  if join else elem.empty_admit())
+        joiner = (0, 1, 3, jax.eval_shape(elem._cache_template))
+        bundle = elem.build_admit([joiner]) if join else elem.empty_admit()
         src = ps.plan.query_sources[0].name
         tick = ps.plan.compiled_serve_tick(state)
         text = tick.lower(params, state, {src: bundle}).as_text()

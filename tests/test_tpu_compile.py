@@ -179,19 +179,20 @@ def test_full_width_prefill_holds_the_flash_kernel(one_chip, stablelm_server,
     assert "tpu_custom_call" in c.as_text()
 
 
+def _join_all(elem):
+    """The admit lanes of a tick on which every slot joins."""
+    return elem.build_admit([(i, 0, 1, None) for i in range(elem.slots)])
+
+
 def test_full_width_join_tick_fits_one_chip(one_chip, stablelm_server):
     """The 24-layer, 8-slot decode tick with every slot joining (the
-    largest tick: the admit bundle carries eight 1024-position caches)."""
+    joiners' 1024-position caches are in their rows already; the bundle
+    carries their lanes)."""
     ps = stablelm_server
     elem = ps.elements["lm"]
     params = _on(one_chip, jax.eval_shape(ps.init, jax.random.PRNGKey(0)))
     state = _on(one_chip, jax.eval_shape(ps.init_state))
-    s = elem.slots
-    lanes = [jax.ShapeDtypeStruct((s,), dt) for dt in
-             (jnp.bool_, jnp.int32, jnp.int32)]
-    caches = [jax.ShapeDtypeStruct((s,) + l.shape, l.dtype) for l in
-              jax.tree_util.tree_leaves(jax.eval_shape(elem._cache_template))]
-    admit = _on(one_chip, StreamBuffer(tensors=(*lanes, *caches), meta={}))
+    admit = _on(one_chip, _join_all(elem))
     src = ps.plan.query_sources[0].name
     tick = ps.plan.compiled_serve_tick(state, donate=True)
     c = tick.lower(params, state, {src: admit}).compile()
@@ -221,12 +222,7 @@ def test_deepseek_tick_fits_one_chip(one_chip, deepseek_server, join):
     state = _on(one_chip, jax.eval_shape(ps.init_state))
     s = elem.slots
     if join:
-        lanes = [jax.ShapeDtypeStruct((s,), dt) for dt in
-                 (jnp.bool_, jnp.int32, jnp.int32)]
-        caches = [jax.ShapeDtypeStruct((s,) + l.shape, l.dtype) for l in
-                  jax.tree_util.tree_leaves(
-                      jax.eval_shape(elem._cache_template))]
-        admit = StreamBuffer(tensors=(*lanes, *caches), meta={})
+        admit = _join_all(elem)
     else:
         admit = StreamBuffer(tensors=(jax.ShapeDtypeStruct((s,), jnp.bool_),),
                              meta={"empty": True})
@@ -237,6 +233,24 @@ def test_deepseek_tick_fits_one_chip(one_chip, deepseek_server, join):
     live = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert live < V5E_HBM, f"tick needs {live / 1e9:.2f} GB"
+
+
+@pytest.mark.parametrize("server", ["stablelm_server", "deepseek_server"])
+def test_full_width_row_write_happens_in_place(one_chip, request, server):
+    """The join's row write at full width, donated as on the chip: the
+    slot table's output aliases its input, so a joiner moves one row and
+    the table is never held twice."""
+    ps = request.getfixturevalue(server)
+    elem = ps.elements["lm"]
+    table = _on(one_chip, jax.eval_shape(ps.init_state)["lm"]["cache"])
+    row = _on(one_chip, jax.eval_shape(elem._cache_template))
+    slot = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    c = elem.row_writer(donate=True).lower(table, slot, row).compile()
+    mem = c.memory_analysis()
+    size = sum(l.size * l.dtype.itemsize
+               for l in jax.tree_util.tree_leaves(table))
+    assert mem.alias_size_in_bytes >= size
+    assert mem.temp_size_in_bytes < size / elem.slots
 
 
 def test_deepseek_expert_layer_work_follows_routed_tokens(one_chip):

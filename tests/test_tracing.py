@@ -6,8 +6,9 @@
 * on, every decode tick holds the expected span tree (each child inside
   its parent) and each request's stamps come in order: arrival, prefill,
   first token, join, finish;
-* the batcher's counters are computed from static shapes: the admit
-  fetch is the joiners times one slot's cache;
+* the batcher's counters are computed from static shapes: each joiner
+  writes one slot's cache on the device, nothing is fetched, and a join
+  tick ships the admit lanes only;
 * the rings stay bounded and say how much they dropped.
 """
 import jax
@@ -136,13 +137,13 @@ def test_on_each_decode_tick_holds_its_span_tree():
     by_id = {s.id: s for s in got.spans}
     names = {s.name for s in got.spans}
     assert {"sched.tick", "lm.admit", "lm.prefill", "lm.decode",
-            "lm.admit.build", "lm.admit.fetch", "lm.admit.copy",
+            "lm.admit.build", "lm.admit.write",
             "lm.decode.dispatch", "lm.decode.fetch", "lm.finish"} <= names
+    assert not {"lm.admit.fetch", "lm.admit.copy"} & names
     want_parent = {"lm.admit": "sched.tick", "lm.decode": "sched.tick",
                    "lm.prefill": "lm.admit",
                    "lm.admit.build": "lm.decode",
-                   "lm.admit.fetch": "lm.admit.build",
-                   "lm.admit.copy": "lm.admit.build",
+                   "lm.admit.write": "lm.decode",
                    "lm.decode.dispatch": "lm.decode",
                    "lm.decode.fetch": "lm.decode", "lm.finish": "lm.decode"}
     for s in got.spans:
@@ -159,12 +160,17 @@ def test_on_each_decode_tick_holds_its_span_tree():
         qb["admit_d2h_bytes"]
     assert sum(d.attrs.get("h2d_bytes", 0) for d in decodes) == \
         qb["admit_h2d_bytes"]
-    # within a join tick the build comes first, then the dispatch, then
-    # the fetch of the answer lanes
+    for key in ("admit_rows", "admit_write_bytes"):
+        assert sum(d.attrs.get(key, 0) for d in decodes) == qb[key] > 0
+    writes = [s for s in got.spans if s.name == "lm.admit.write"]
+    assert sum(w.attrs["n"] for w in writes) == qb["admit_rows"]
+    # within a join tick the lanes' build comes first, then the row
+    # writes, the dispatch, and the fetch of the answer lanes
     d = next(d for d in decodes if d.attrs["joins"])
     kids = sorted((s for s in got.spans if s.parent == d.id),
                   key=lambda s: s.start)
-    assert [s.name for s in kids][:3] == ["lm.admit.build",
+    assert [s.name for s in kids][:4] == ["lm.admit.build",
+                                          "lm.admit.write",
                                           "lm.decode.dispatch",
                                           "lm.decode.fetch"]
     assert all(a.end <= b.start for a, b in zip(kids, kids[1:]))
@@ -187,7 +193,7 @@ def test_on_request_stamps_come_in_order_and_name_their_spans():
     assert {s.rid for s in got.spans if s.name == "lm.decode"} == {None}
 
 
-def test_admit_fetch_counts_joiners_times_one_slots_cache():
+def test_admit_writes_one_slots_cache_per_joiner_and_ships_lanes_only():
     from repro.models import transformer
     _, rt = _lm()
     elem = next(e for e in rt.devices[0].runs[0].pipe.elements.values()
@@ -195,14 +201,16 @@ def test_admit_fetch_counts_joiners_times_one_slots_cache():
     one = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(
         transformer.cache_init(elem.cfg, 1, elem.max_seq)))
     qb = rt.stats()["query_batching"]
-    # each started stream joined a slot once
+    # each started stream joined a slot once: one row written on the
+    # device, nothing fetched to the host
     joined = qb["streams_started"] - len(
         next(iter(rt._batchers.values()))._waiting)
-    assert qb["admit_d2h_bytes"] == joined * one
-    # a join tick ships the whole slot table's admit rows
+    assert qb["admit_rows"] == joined
+    assert qb["admit_write_bytes"] == joined * one
+    assert qb["admit_d2h_bytes"] == 0
+    # a join tick ships the admit lanes only
     lanes = elem.slots * (1 + 4 + 4)
-    assert qb["admit_h2d_bytes"] == qb["join_ticks"] * (
-        lanes + elem.slots * one)
+    assert qb["admit_h2d_bytes"] == qb["join_ticks"] * lanes
     assert qb["slot_ticks"] <= qb["decode_ticks"] * elem.slots
 
 
