@@ -51,21 +51,21 @@ correct — and picks the faster for that size thereafter; ``"always"`` /
 path's semantics regardless of host speed).  Placement stays transparent to
 elements and clients, NNStreamer-style: only latency changes.
 
-Fused wire path (DESIGN.md §5, default on): the batcher does NOT decode
-requests at gather time.  Wire-form requests group by **(codec, wire
-structure)** — consecutive, same as mixed-structure grouping, and since a
-codec determines its wire pytree this subsumes the old mixed-codec
-stacking — and each group serves through the codec-fused executable
+Fused wire path (DESIGN.md §5): the batcher does NOT decode requests at
+gather time.  Wire-form requests group by **(codec, wire
+structure)** — consecutive, same as mixed-structure grouping; a codec
+determines its wire pytree, so mixed-codec batches split per codec — and
+each group serves through the codec-fused executable
 (``plan.compiled_serve_batch(codec=...)``): per-request decode, stacked
 scan, and per-frame answer re-encode all inside ONE jit.  Routing meta is
 hoisted on the host exactly as before; the stacked wire answers are fetched
 with ONE device_get, split as numpy, and pushed through the serversink's
 wire-level route (``push_wire`` — byte accounting from static shapes, no
 sync); deferred sparse-truncation counts ride out of the jit as one array
-and sync once per flush.  Groups a mesh may take keep the PR-4 eager wire
-path (host decode → placement probe → sharded serve → host encode), so the
-sharding guarantees are untouched; ``fused=False`` restores the eager path
-everywhere (the benchmark baseline).
+and sync once per flush.  Groups a mesh may take, and ``none``-codec groups
+(nothing to fuse), serve through the dense path instead (one batched host
+decode → placement probe → sharded or single-device serve → per-answer
+encode), so the sharding guarantees are untouched.
 
 Admission layer (DESIGN.md §9): every batcher's queueing now runs through
 one shared :class:`~repro.core.admission.AdmissionQueue` — requests pop
@@ -153,7 +153,7 @@ class QueryBatcher:
     def __init__(self, endpoint: QueryServerEndpoint, run: Any,
                  policy: BatchingPolicy,
                  inline_step: Optional[Callable[[], Any]] = None,
-                 mesh=None, shard_mode: str = "auto", fused: bool = True,
+                 mesh=None, shard_mode: str = "auto",
                  on_orphans: Optional[Callable[[int], None]] = None,
                  *, qos: Optional[QoSConfig] = None,
                  clock: Optional[Callable[[], int]] = None):
@@ -178,8 +178,6 @@ class QueryBatcher:
         #: CRC + dedup triage first.  None (the default) is the pre-§10
         #: wire, bit for bit.
         self.guard = None
-        #: codec-fused serving (module docstring); False = PR-4 eager codec
-        self.fused = fused
         #: jax Mesh to lay batches out on (None = single-device serving)
         self.mesh = mesh
         #: sharded-executable placement policy (module docstring)
@@ -277,41 +275,29 @@ class QueryBatcher:
                 break                   # serve budget spent this tick
             raws = [r.raw for r in recs]
             idx = 0
-            if self.fused:
-                for pairs, codec in self._group_wire(raws):
-                    if not self.endpoint.alive:
-                        self._shed_flush_remainder(recs[idx:])
-                        break
-                    if codec.partition(":")[0] == "none" or \
-                            self._mesh_may_take(len(pairs)):
-                        # nothing to fuse for "none" (decode/encode are
-                        # identity — the fused executable would only add a
-                        # per-flush answer fetch), and mesh placement needs
-                        # dense frames (probe + sharded executable): both
-                        # keep the eager wire path per PR-4, lazy answers —
-                        # but the request decode still batches into one
-                        # stacked dispatch
-                        decoded = comp.decode_batch(
-                            [clean for clean, _ in pairs], codec)
-                        self._serve_batched(
-                            [(dec, routing) for dec, (_, routing)
-                             in zip(decoded, pairs)])
-                    else:
-                        self._serve_batched_wire(pairs, codec)
-                    for rec in recs[idx:idx + len(pairs)]:
-                        adm.mark_served(rec)
-                    idx += len(pairs)
-                    served += len(pairs)
-            else:
-                for group in self._group(raws):
-                    if not self.endpoint.alive:
-                        self._shed_flush_remainder(recs[idx:])
-                        break
-                    self._serve_batched(group)
-                    for rec in recs[idx:idx + len(group)]:
-                        adm.mark_served(rec)
-                    idx += len(group)
-                    served += len(group)
+            for pairs, codec in self._group_wire(raws):
+                if not self.endpoint.alive:
+                    self._shed_flush_remainder(recs[idx:])
+                    break
+                if codec.partition(":")[0] == "none" or \
+                        self._mesh_may_take(len(pairs)):
+                    # nothing to fuse for "none" (decode/encode are
+                    # identity — the fused executable would only add a
+                    # per-flush answer fetch), and mesh placement needs
+                    # dense frames (probe + sharded executable): both serve
+                    # dense with lazy answers — but the request decode
+                    # still batches into one stacked dispatch
+                    decoded = comp.decode_batch(
+                        [clean for clean, _ in pairs], codec)
+                    self._serve_batched(
+                        [(dec, routing) for dec, (_, routing)
+                         in zip(decoded, pairs)])
+                else:
+                    self._serve_batched_wire(pairs, codec)
+                for rec in recs[idx:idx + len(pairs)]:
+                    adm.mark_served(rec)
+                idx += len(pairs)
+                served += len(pairs)
         if served:
             self.flushes += 1
         return served
@@ -404,33 +390,14 @@ class QueryBatcher:
                                 if k not in _ROUTING_KEYS})
         return clean, routing
 
-    @staticmethod
-    def _structure(buf: StreamBuffer) -> Tuple:
-        return structure_key(buf)
-
-    def _group(self, raws: List[StreamBuffer]):
-        """Split decoded requests into consecutive same-structure groups,
-        preserving arrival order (so server state still threads through in
-        FIFO order even when client caps are mixed)."""
-        groups: List[List[Tuple[StreamBuffer, Dict]]] = []
-        last_key = None
-        for raw in raws:
-            clean, routing = self._decode(raw)
-            key = self._structure(clean)
-            if groups and key == last_key:
-                groups[-1].append((clean, routing))
-            else:
-                groups.append([(clean, routing)])
-                last_key = key
-        return groups
-
     def _group_wire(self, raws: List[StreamBuffer]):
-        """Fused-path grouping: consecutive same-(codec, WIRE structure)
-        runs of raw requests, arrival order preserved — no host decode.
-        The codec is part of the key because it is the fused executable's
-        static trace parameter (and two codecs' wire pytrees differ
-        anyway), so mixed-codec batches split exactly like mixed-structure
-        batches always have.  Yields ``([(clean_wire, routing), ...],
+        """Split raw requests into consecutive same-(codec, WIRE structure)
+        runs, preserving arrival order (so server state still threads
+        through in FIFO order even when client caps are mixed) — no host
+        decode.  The codec is part of the key because it is the fused
+        executable's static trace parameter (and two codecs' wire pytrees
+        differ anyway), so mixed-codec batches split exactly like
+        mixed-structure batches.  Yields ``([(clean_wire, routing), ...],
         codec)`` — the hoisted pairs the key was built from, so serving
         never re-hoists."""
         groups: List[Tuple[List[Tuple[StreamBuffer, Dict]], str]] = []
@@ -438,7 +405,7 @@ class QueryBatcher:
         for raw in raws:
             codec = raw.meta.get("codec", "none")
             pair = self._hoist_wire(raw)
-            key = (codec, self._structure(pair[0]))
+            key = (codec, structure_key(pair[0]))
             if groups and key == last_key:
                 groups[-1][0].append(pair)
             else:
@@ -459,11 +426,11 @@ class QueryBatcher:
     def _mesh_may_take(self, n: int) -> bool:
         """Whether mesh placement might claim this group — those groups
         need host-decoded dense frames (calibration probe + sharded
-        executable input), so they keep the eager wire path.  A batch size
-        whose calibrated placement already said "single" is NOT claimed:
-        forfeiting codec fusion there would re-pay the eager per-frame
-        codec cost for nothing (only the first, probe-carrying flush of a
-        size goes eager in auto mode)."""
+        executable input), so they serve dense.  A batch size whose
+        calibrated placement already said "single" is NOT claimed:
+        forfeiting codec fusion there would re-pay the per-frame answer
+        encode for nothing (only the first, probe-carrying flush of a size
+        serves dense in auto mode)."""
         if self.mesh is None or self.shard_mode == "never":
             return False
         if not self.run.pipe.plan.shardable_batch(n, self.run.state,
@@ -621,11 +588,11 @@ class QueryBatcher:
             for name, frames in wire_frames.items():
                 wb = frames[i]
                 # per-sink deferred loss accounting: each sink's answer
-                # carries ITS OWN truncation count, as the eager per-buffer
-                # encode would stamp it
+                # carries ITS OWN truncation count, as the dense path's
+                # per-answer encode would stamp it
                 frame_dropped = (comp.account_sparse_dropped(
                     dropped[name][:, i]) if name in dropped else 0)
-                # meta layering matches the eager path: scan answer meta,
+                # meta layering matches the dense path: scan answer meta,
                 # then routing, then the wire-form claims encode would stamp
                 meta = {**wb.meta, **routing, "codec": base_codec}
                 if frame_dropped:
@@ -915,14 +882,15 @@ class StreamingQueryBatcher(QueryBatcher):
             serve = plan.compiled_serve_tick(run.state)
             bundle = elem.build_admit(admits)
             if admits:
+                rows = [(slot, cache) for slot, *_, cache in admits]
                 with tracing.span("lm.admit.write") as wsp:
-                    wsp.set(n=len(admits))
+                    wsp.set(n=len(rows))
                     run.state = {**run.state, elem.name: elem.write_rows(
-                        run.state[elem.name], admits)}
+                        run.state[elem.name], rows)}
                 # counted from static shapes: the joiners' caches written
                 # into their slot rows on the device (nothing fetched), and
                 # what the host ships: the lanes and any host-resident cache
-                caches = [cache for *_, cache in admits]
+                caches = [cache for _, cache in rows]
                 written = _nbytes(caches)
                 shipped = _nbytes(bundle.tensors) + sum(
                     int(leaf.nbytes)
@@ -1062,9 +1030,10 @@ class StageQueryBatcher(QueryBatcher):
       its own slice of a dead stage's state from the coordinator's
       retained activations).
     * ``"decode"``  — one slot-table hop through ``compiled_serve_tick``:
-      ``meta["admit"]`` maps joining slots to parked stream ids (merged
-      under the admit mask inside the jit), ``meta["live"]`` prunes parked
-      caches of finished streams.
+      ``meta["admit"]`` maps joining slots to parked stream ids (each
+      parked cache is written into its slot row on the device before the
+      hop, ``write_rows``), ``meta["live"]`` prunes parked caches of
+      finished streams.
 
     Epoch fencing: every §6 reconfig of this pipeline bumps
     ``endpoint.spec["serve_epoch"]`` — the coordinator trusts a stage's
@@ -1142,8 +1111,8 @@ class StageQueryBatcher(QueryBatcher):
 
     def _serve_decode_hop(self, clean: StreamBuffer, elem):
         x, active = clean.tensors
-        admits = [(int(slot), self._parked.pop(int(sid)))
-                  for slot, sid in clean.meta.get("admit", ())]
+        rows = [(int(slot), self._parked.pop(int(sid)))
+                for slot, sid in clean.meta.get("admit", ())]
         live = clean.meta.get("live")
         if live is not None:
             keep = set(int(s) for s in live)
@@ -1154,8 +1123,11 @@ class StageQueryBatcher(QueryBatcher):
         src = plan.query_sources[0].name
         sink = plan.query_sinks[0].name
         serve = plan.compiled_serve_tick(run.state)
+        if rows:
+            run.state = {**run.state, elem.name: elem.write_rows(
+                run.state[elem.name], rows)}
         outputs, run.state = serve(run.params, run.state,
-                                   {src: elem.build_hop(x, active, admits)})
+                                   {src: StreamBuffer(tensors=(x, active))})
         self.decode_hops += 1
         run.frames += 1
         n_active = int(np.asarray(active).sum())
@@ -1214,8 +1186,8 @@ class StagedStreamingBatcher(StreamingQueryBatcher):
     standby, win-back, or a §6 swap — the coordinator rebuilds ONLY stage
     k: per live stream, replay the retained activations through the
     stage's prefill/replay verbs (bitwise by construction: identical
-    traced programs on identical inputs), then re-merge parked caches
-    into slot rows under the next hop's admit mask.  Other stages are
+    traced programs on identical inputs), then the next hop's admit list
+    writes the parked caches into their slot rows.  Other stages are
     untouched; no generation restarts; zero tokens drop.
 
     A hop that fails MID-TICK stalls the tick: stages < k already
@@ -1314,7 +1286,7 @@ class StagedStreamingBatcher(StreamingQueryBatcher):
                 self.stage_replay_steps[k] = \
                     self.stage_replay_steps.get(k, 0) + 1
         # slotted streams' rows on the new stage are garbage until their
-        # freshly parked caches re-merge at the next decode hop
+        # freshly parked caches are written into them at the next decode hop
         rd = self._readmit.setdefault(k, {})
         for slot, rec in self._slots.items():
             rd[slot] = rec["sid"]
@@ -1476,11 +1448,11 @@ class StagedStreamingBatcher(StreamingQueryBatcher):
         run = self.run
         elem = self._serve_elem()
         free = sorted(s for s in range(elem.slots) if s not in self._slots)
-        admits0 = []
+        rows = []
         while free and self._waiting:
             rec = self._waiting.pop(0)
             slot = free.pop(0)
-            admits0.append((slot, rec["cache0"]))
+            rows.append((slot, rec["cache0"]))
             rec["cache0"] = None    # stage 0's slice lives in plan state now
             self._slots[slot] = rec
             for k in range(1, self.n_stages):
@@ -1497,9 +1469,11 @@ class StagedStreamingBatcher(StreamingQueryBatcher):
         src = plan.query_sources[0].name
         sink = plan.query_sinks[0].name
         serve = plan.compiled_serve_tick(run.state)
+        if rows:
+            run.state = {**run.state, elem.name: elem.write_rows(
+                run.state[elem.name], rows)}
         outputs, run.state = serve(run.params, run.state,
-                                   {src: elem.build_hop(tok, active,
-                                                        admits0)})
+                                   {src: StreamBuffer(tensors=(tok, active))})
         y = np.asarray(jax.device_get(outputs[sink].tensors[0]))
         self.decode_ticks += 1
         run.frames += 1
@@ -1519,8 +1493,8 @@ class StagedStreamingBatcher(StreamingQueryBatcher):
         while k < self.n_stages:
             # secure the stage BEFORE assembling the admit list: a trust
             # break replays into _readmit[k], and those freshly parked
-            # caches must merge on THIS hop — assembling first would ship
-            # an empty admit and decode the standby's zero rows
+            # caches must be written on THIS hop — assembling first would
+            # ship an empty admit and decode the standby's zero rows
             self._ensure_stage(k)
             rd = self._readmit.get(k, {})
             admit = tuple((int(slot), int(sid))

@@ -183,17 +183,11 @@ class ModelServeElement(Element):
                                       st["remaining"])
                 active = st["active"] | admit_mask
 
-        # 2. decode tick: each slot is an independent b=1 lm_decode — the
-        #    same traced program sequential per-request decode runs — vmapped
-        #    over the slot axis, so the S slots' matmuls fuse into batched
-        #    contractions (the continuous-batching throughput lever) while
-        #    each slot's values stay the per-request values (slot rows are
-        #    independent rows of every batched matmul — bitwise parity is
-        #    pinned in tests/test_model_serving.py).  Inactive slots compute
-        #    on zero caches and are discarded by the select below.
-        #    A model with experts routes the whole slot batch as one group
-        #    (``moe_serve``'s vmap rule) and counts the held experts' load.
-        def slot_step(c, tok, act):
+        # 2. decode tick: each slot is an independent b=1 lm_decode
+        #    (``step_slots``).  A model with experts routes the whole slot
+        #    batch as one group (``moe_serve``'s vmap rule) and counts the
+        #    held experts' load.
+        def step(c, tok, act):
             if cfg.n_experts:
                 logits, new_c, routes = transformer.lm_decode(
                     params, cfg, tok[None], c, with_routes=True)
@@ -203,11 +197,9 @@ class ModelServeElement(Element):
                                                       c)
                 load = None
             new_tok = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
-            c_out = jax.tree_util.tree_map(
-                lambda old, new: jnp.where(act, new, old), c, new_c)
-            return c_out, jnp.where(act, new_tok, tok), load
+            return new_c, jnp.where(act, new_tok, tok), load
 
-        cache, token, load = jax.vmap(slot_step)(cache, token, active)
+        cache, token, load = self.step_slots(step, cache, token, active)
 
         # 3. retire: a slot leaves the batch the tick its budget hits zero
         emitted = active
@@ -223,6 +215,25 @@ class ModelServeElement(Element):
         if load is not None:
             lanes += (jnp.sum(load, axis=0),)
         return [inputs[0].with_(tensors=lanes, meta={})]
+
+    @staticmethod
+    def step_slots(step, cache, x, active):
+        """The slot commit both serve elements' ticks run: ``step(c, x, act)
+        -> (new_c, *outs)`` on each slot as an independent b=1 program — the
+        same traced program sequential per-request decode runs — vmapped
+        over the slot axis, so the S slots' matmuls fuse into batched
+        contractions (the continuous-batching throughput lever) while each
+        slot's values stay the per-request values (slot rows are independent
+        rows of every batched matmul — bitwise parity is pinned in
+        tests/test_model_serving.py).  Each slot's cache commits under
+        ``active``: an inactive slot computes on its stale rows and keeps
+        them.  Returns ``(cache, *outs)`` stacked over slots."""
+        def slot_step(c, xx, act):
+            new_c, *outs = step(c, xx, act)
+            c_out = jax.tree_util.tree_map(
+                lambda old, new: jnp.where(act, new, old), c, new_c)
+            return (c_out, *outs)
+        return jax.vmap(slot_step)(cache, x, active)
 
     # -- routed experts -------------------------------------------------------
     def _held_load(self, routes):
@@ -341,14 +352,14 @@ class ModelServeElement(Element):
                 rem[slot] = r
         return StreamBuffer(tensors=(mask, tok, rem), meta={})
 
-    def write_rows(self, state: dict, admits) -> dict:
+    def write_rows(self, state: dict, rows) -> dict:
         """Write each joiner's prefilled b=1 cache into its slot row of the
         slot table, on the device; returns the element state with the rows
-        written.  ``admits`` is ``build_admit``'s list.  A host-resident
-        (numpy) cache is shipped by the call."""
+        written.  ``rows`` is a list of ``(slot, b1_cache)``.  A
+        host-resident (numpy) cache is shipped by the call."""
         write = self.row_writer()
         cache = state["cache"]
-        for slot, _, _, row in admits:
+        for slot, row in rows:
             cache = write(cache, np.int32(slot), row)
         return {**state, "cache": cache}
 
@@ -390,10 +401,10 @@ class ModelServeStageElement(ModelServeElement):
     inert until re-admitted.
 
     Input frame (a hop bundle assembled host-side):
-      ``(x_in[S,...], active[S])`` + ``meta={"empty": True}`` steady-state,
-      or ``(x_in, active, admit_mask[S], *admit_cache_leaves)`` on a tick
-      with joins (parked b=1 prefill caches scattered into slot rows).
-      ``x_in`` is ``token[S] int32`` on stage 0, acts ``[S, 1, d]`` after.
+      ``(x_in[S,...], active[S])`` on every tick.  ``x_in`` is ``token[S]
+      int32`` on stage 0, acts ``[S, 1, d]`` after.  Joiners' parked b=1
+      caches are already in their slot rows (``write_rows`` ran before the
+      hop), so one hop program serves join and steady ticks alike.
     Output frame: next-stage acts ``[S, 1, d]`` (zeroed where inactive),
       or ``token[S] int32`` from the last stage.
     """
@@ -436,76 +447,30 @@ class ModelServeStageElement(ModelServeElement):
                                         self.n_stages)
 
     def init_state(self) -> dict:
-        s = self.slots
-        cache = jax.tree_util.tree_map(
-            lambda l: jnp.zeros((s,) + tuple(jnp.shape(l)), l.dtype),
-            self._cache_template())
-        return {"cache": cache}
+        return {"cache": super().init_state()["cache"]}
 
     # -- the jitted stage hop -------------------------------------------------
     def apply(self, params, inputs: List[StreamBuffer],
               ctx: PipelineContext = None) -> List[StreamBuffer]:
         from ..models import transformer
         cfg = self.cfg
-        st = ctx.get_state(self.name)
-        ts = inputs[0].tensors
-        x_in, active = ts[0], ts[1]
-        if inputs[0].meta.get("empty"):
-            cache = st["cache"]
-        else:
-            treedef = jax.tree_util.tree_structure(self._cache_template())
-            admit_mask = ts[2]
-            admit_cache = jax.tree_util.tree_unflatten(treedef, list(ts[3:]))
-
-            def merge(old, new):
-                m = admit_mask.reshape((-1,) + (1,) * (old.ndim - 1))
-                return jnp.where(m, new, old)
-            cache = jax.tree_util.tree_map(merge, st["cache"], admit_cache)
-
+        x_in, active = inputs[0].tensors
         stage, n_stages = self.stage, self.n_stages
 
-        def slot_step(c, x, act):
+        def step(c, x, act):
             out, new_c = transformer.stage_decode(params, cfg, stage,
                                                   n_stages, x[None], c)
-            c_out = jax.tree_util.tree_map(
-                lambda old, new: jnp.where(act, new, old), c, new_c)
             if stage == n_stages - 1:
                 y = jnp.argmax(out[0], axis=-1).astype(jnp.int32)
-                return c_out, jnp.where(act, y, 0)
-            return c_out, jnp.where(act, out[0], jnp.zeros_like(out[0]))
+                return new_c, jnp.where(act, y, 0)
+            return new_c, jnp.where(act, out[0], jnp.zeros_like(out[0]))
 
-        cache, y = jax.vmap(slot_step)(cache, x_in, active)
+        cache, y = self.step_slots(step, ctx.get_state(self.name)["cache"],
+                                   x_in, active)
         ctx.set_state(self.name, {"cache": cache})
         return [inputs[0].with_(tensors=(y,), meta={})]
 
-    # -- host half (hop bundle assembly + stage-local prefill/replay) ---------
-    def _zero_hop(self):
-        """Zero admit-cache rows ``build_hop`` scatters parked caches into."""
-        if getattr(self, "_zero_hop_base", None) is None:
-            s = self.slots
-            leaves = [np.zeros((s,) + tuple(jnp.shape(l)),
-                               np.dtype(str(l.dtype)))
-                      for l in jax.tree_util.tree_leaves(self._cache_template())]
-            self._zero_hop_base = (np.zeros((s,), np.bool_), *leaves)
-        return self._zero_hop_base
-
-    def build_hop(self, x_in, active, admits) -> StreamBuffer:
-        """Assemble one decode-hop bundle.  ``admits`` is a list of
-        ``(slot, b1_cache)`` parked prefill caches joining this tick; empty
-        admits give the structurally tiny steady-state bundle."""
-        if not admits:
-            return StreamBuffer(tensors=(x_in, active),
-                                meta={"empty": True})
-        base = self._zero_hop()
-        mask = base[0].copy()
-        leaves = [l.copy() for l in base[1:]]
-        for slot, cache in admits:
-            mask[slot] = True
-            for dst, src in zip(leaves, jax.tree_util.tree_leaves(
-                    jax.device_get(cache))):
-                dst[slot] = src
-        return StreamBuffer(tensors=(x_in, active, mask, *leaves), meta={})
-
+    # -- host half (stage-local prefill/replay) -------------------------------
     def host_stage_prefill(self, params, x):
         """Stage-local prefill: tokens int32[L] (stage 0) or boundary acts
         float[1, L, d] -> (boundary out, b=1 stage cache).  Jitted per

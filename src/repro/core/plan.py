@@ -570,8 +570,8 @@ class ExecutionPlan:
         cache key carries the codec fingerprint, so codec-fused and plain
         executables never collide — and neither do two codecs (quant8 and
         sparse trace different wire pytrees).  Codec fusion composes with
-        single-device serving only; mesh placement keeps the PR-4 eager
-        wire path (the batcher decides per group).
+        single-device serving only; a group the mesh may take serves
+        dense (host-decoded frames; the batcher decides per group).
 
         The mesh executable moves the stack/split to the HOST (numpy, zero
         XLA dispatches) and keeps the jit boundary stacked-and-sharded:
@@ -589,7 +589,7 @@ class ExecutionPlan:
         if codec is not None:
             if mesh is not None:
                 raise ValueError("codec-fused serving is single-device; "
-                                 "mesh groups keep the eager wire path")
+                                 "mesh groups serve dense frames")
             def serve_wire(params, state, frames, _self=self, _codec=codec):
                 return _self.serve_batch_wire(params, state, frames, _codec)
             fns[key] = jax.jit(serve_wire,

@@ -68,19 +68,18 @@ groups, stateful plans, and 1-device meshes serve exactly like
 batches' orphans identically (the mesh only places compute; the
 request/answer plumbing is untouched).
 
-Fused wire path (default on, ``fused_wire=True``, DESIGN.md §5): the whole
-codec/transport hot path of a tick runs batched.  Client pipelines whose
-only impure elements are their query clients start and resume through
-jitted deferred SEGMENTS (``plan.run_deferred_compiled``) instead of
-interpreted walks; a dispatch round gathers every freshly paused frame,
-encodes the requests per (codec, structure) group in ONE batched codec
-dispatch, and pushes in arrival order; each server flush serves wire-form
-groups through the codec-fused executable (decode → stacked scan → answer
-re-encode inside one jit, ``core/batching.py``); and the drain batch-
-decodes the round's answers per group before resuming.  Bitwise identical
-to the eager path at every seam (codec kernels batch by tile/block merge,
-segments jit the same program); ``fused_wire=False`` restores the PR-4
-eager wire path end to end — the benchmark baseline.
+Fused wire path (DESIGN.md §5): the whole codec/transport hot path of a
+tick runs batched.  Client pipelines whose only impure elements are their
+query clients start and resume through jitted deferred SEGMENTS
+(``plan.run_deferred_compiled``) instead of interpreted walks; a dispatch
+round gathers every freshly paused frame, encodes the requests per (codec,
+structure) group in ONE batched codec dispatch, and pushes in arrival
+order; each server flush serves wire-form groups through the codec-fused
+executable (decode → stacked scan → answer re-encode inside one jit,
+``core/batching.py``); and the drain batch-decodes the round's answers per
+group before resuming.  Bitwise identical to per-frame serving at every
+seam (codec kernels batch by tile/block merge, segments jit the same
+program).
 
 Statistics (frames, drops, bytes, bursts, batches, redispatches, per-sink
 pts) feed the Fig. 7 benchmark.
@@ -183,7 +182,6 @@ class Runtime:
                  query_batch=DEFAULT_QUERY_BATCH,
                  lease_ticks: Optional[int] = None,
                  mesh=None, shard_mode: str = "auto",
-                 fused_wire: bool = True,
                  park_deadline_ticks: Optional[int] = None,
                  qos: Optional[QoSConfig] = None,
                  delivery: Optional["netfault.DeliveryPolicy"] = None):
@@ -210,9 +208,6 @@ class Runtime:
             raise ValueError(f"shard_mode {shard_mode!r} not in "
                              f"('auto', 'always', 'never')")
         self.shard_mode = shard_mode
-        #: fused batched wire path (module docstring; DESIGN.md §5) —
-        #: False restores the PR-4 eager codec path end to end
-        self.fused_wire = bool(fused_wire)
         #: tenant-aware admission policy (DESIGN.md §9): None keeps every
         #: batcher's AdmissionQueue in exact global-FIFO pass-through —
         #: the pre-QoS fabric, bit for bit
@@ -317,7 +312,6 @@ class Runtime:
                         e.endpoint, run, self.batching,
                         inline_step=lambda r=run: self._run_once(r),
                         mesh=self.mesh, shard_mode=self.shard_mode,
-                        fused=self.fused_wire,
                         on_orphans=self._count_orphans,
                         # hop traffic is NEVER re-scheduled (each hop is one
                         # step of a stream the stage-0 coordinator already
@@ -331,7 +325,6 @@ class Runtime:
                         e.endpoint, run, self.batching,
                         inline_step=lambda r=run: self._run_once(r),
                         mesh=self.mesh, shard_mode=self.shard_mode,
-                        fused=self.fused_wire,
                         on_orphans=self._count_orphans,
                         tick_source=lambda: self.ticks,
                         broker=self.broker,
@@ -345,7 +338,6 @@ class Runtime:
                         e.endpoint, run, self.batching,
                         inline_step=lambda r=run: self._run_once(r),
                         mesh=self.mesh, shard_mode=self.shard_mode,
-                        fused=self.fused_wire,
                         on_orphans=self._count_orphans,
                         tick_source=lambda: self.ticks,
                         qos=self.qos, clock=lambda: self.ticks)
@@ -354,7 +346,6 @@ class Runtime:
                         e.endpoint, run, self.batching,
                         inline_step=lambda r=run: self._run_once(r),
                         mesh=self.mesh, shard_mode=self.shard_mode,
-                        fused=self.fused_wire,
                         on_orphans=self._count_orphans,
                         qos=self.qos, clock=lambda: self.ticks)
                 if self.delivery is not None:
@@ -514,14 +505,14 @@ class Runtime:
     def _begin_deferred(self, run: _PipeRun
                         ) -> Optional[Tuple[_PipeRun, PendingQuery]]:
         """Begin a frame for a pipeline containing query clients: the plan
-        pauses at the first client.  On the fused wire path, plans whose
-        only impure elements are query clients run the walk as ONE jitted
-        segment (plan.run_deferred_compiled) — bitwise the interpreted
-        deferral without its per-element dispatch cost.  Returns the paused
+        pauses at the first client.  Plans whose only impure elements are
+        query clients run the walk as ONE jitted segment
+        (plan.run_deferred_compiled) — bitwise the interpreted deferral
+        without its per-element dispatch cost.  Returns the paused
         frame (NOT yet dispatched — the tick batches a whole round's
         request encodes), or None if the frame completed without pausing."""
         plan = run.pipe.plan
-        if self.fused_wire and plan.deferred_compilable:
+        if plan.deferred_compilable:
             res = plan.run_deferred_compiled(run.params, run.state)
         else:
             res = plan.run_deferred(run.params, run.state)
@@ -561,23 +552,15 @@ class Runtime:
 
     def _dispatch_round(self, fresh: List[Tuple[_PipeRun, PendingQuery]]
                         ) -> List[Tuple[_PipeRun, PendingQuery]]:
-        """Ship a round of freshly paused frames.  Fused wire path: resolve
-        every endpoint first (unplaceable frames park before any encode is
-        paid), batch-encode the requests per codec group, then push in
-        arrival order — server channels stay FIFO, so batching the encodes
-        never reorders what the scan serves.  Early flushes still fire the
-        moment an endpoint's gather fills.  Legacy path: per-frame
-        ``_dispatch_query`` exactly as before."""
+        """Ship a round of freshly paused frames: resolve every endpoint
+        first (unplaceable frames park before any encode is paid),
+        batch-encode the requests per codec group, then push in arrival
+        order — server channels stay FIFO, so batching the encodes never
+        reorders what the scan serves.  Early flushes still fire the moment
+        an endpoint's gather fills."""
         if not fresh:
             return []
         out: List[Tuple[_PipeRun, PendingQuery]] = []
-        if not self.fused_wire:
-            for run, pq in fresh:
-                if self._dispatch_query(pq):
-                    out.append((run, pq))
-                else:
-                    self._park(run, pq)
-            return out
         ready = []
         for run, pq in fresh:
             qc = pq.client
@@ -794,10 +777,9 @@ class Runtime:
         and a chain of re-dispatches is bounded by the number of live
         servers (nothing revives mid-drain; revivals are tick events).
 
-        Fused wire path: the round's answers are popped raw and decoded in
-        one batched codec dispatch per (codec, structure) group before the
-        resumes — bitwise the per-frame decode, minus ``batch × tensors``
-        eager dispatches."""
+        The round's answers are popped raw and decoded in one batched codec
+        dispatch per (codec, structure) group before the resumes — bitwise
+        the per-frame decode, minus ``batch × tensors`` eager dispatches."""
         pending = list(pending)
         while pending:
             for batcher in self._batchers.values():
@@ -872,11 +854,9 @@ class Runtime:
 
     def _decode_answers(self, pairs) -> List[StreamBuffer]:
         """Decode a drain round's raw answers, batched per (codec,
-        structure) group on the fused path, per frame on the legacy one."""
+        structure) group."""
         from ..core import compression as comp
         with tracing.span("sched.decode_answers"):
-            if not self.fused_wire:
-                return [comp.decode(raw, qc.codec) for qc, raw in pairs]
             return self._codec_round(pairs, comp.decode_batch)
 
     # -- burst draining ----------------------------------------------------------
@@ -1027,7 +1007,7 @@ class Runtime:
                     else:
                         self._run_once(run)
             # the whole round's request encodes batch into one codec dispatch
-            # per group before anything ships (fused path; arrival order kept)
+            # per group before anything ships (arrival order kept)
             pending.extend(self._dispatch_round(fresh))
             self._drain_queries(pending)
 

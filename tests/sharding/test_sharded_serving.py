@@ -121,8 +121,8 @@ class TestBitwiseParity:
 
     def test_uniform_codec_groups_still_shard_bitwise(self):
         """PR-5 composition rule: codec fusion is single-device, so a codec
-        group the mesh may take keeps the PR-4 eager wire path (host decode
-        → placement → sharded serve → host encode).  A full batch of
+        group the mesh may take serves dense (host decode → placement →
+        sharded serve → host encode).  A full batch of
         same-codec clients therefore still shards — and stays bitwise with
         the meshless runtime."""
         def build(mesh):
@@ -161,23 +161,27 @@ class TestBitwiseParity:
             for a, b in zip(_responses(mr), _responses(rr)):
                 np.testing.assert_array_equal(a, b)
 
-    def test_eager_wire_path_keeps_mixed_codec_sharding(self):
-        """The PR-4 behavior survives verbatim under fused_wire=False:
-        codec is routing meta there, mixed codecs stack into one sharded
-        batch, answers bitwise vs the meshless eager runtime."""
+    def test_mixed_codec_groups_that_tile_the_mesh_shard(self):
+        """Mixed codecs split per codec, and a per-codec group that tiles
+        the 8-way mesh still shards under ``shard_mode="always"``: 8 "none"
+        then 8 quant8 clients make two groups of 8 per tick, both served
+        sharded (a group the mesh may take serves dense, so none fuses),
+        answers bitwise vs the meshless runtime, where quant8 fuses."""
         def build(mesh):
-            rt = Runtime(query_batch=8, mesh=mesh, shard_mode="always",
-                         fused_wire=False)
+            rt = Runtime(query_batch=16, mesh=mesh, shard_mode="always")
             _server(rt)
-            runs = _clients(rt, 4, codec="none") + \
-                _clients(rt, 4, codec="quant8")
+            runs = _clients(rt, 8, codec="none") + \
+                _clients(rt, 8, codec="quant8")
             rt.run(2)
             return rt, runs
 
         rt_m, m_runs = build(make_host_mesh())
-        _, ref_runs = build(None)
-        assert rt_m.stats()["query_batching"]["sharded_frames"] == 16
+        rt_r, ref_runs = build(None)
+        qb = rt_m.stats()["query_batching"]
+        assert qb["sharded_frames"] == 32 and qb["fused_frames"] == 0
+        assert rt_r.stats()["query_batching"]["fused_frames"] == 16
         for mr, rr in zip(m_runs, ref_runs):
+            assert len(_responses(mr)) == 2
             for a, b in zip(_responses(mr), _responses(rr)):
                 np.testing.assert_array_equal(a, b)
 

@@ -219,22 +219,28 @@ class TestBatchingMechanics:
             for a, b in zip(_responses(br), _responses(sr)):
                 np.testing.assert_array_equal(a, b)
 
-    def test_eager_wire_path_still_batches_mixed_codecs_together(self):
-        """The PR-4 eager path (fused_wire=False) is preserved as the
-        benchmark baseline: codec is routing meta there, one batch per
-        tick, and it still agrees bitwise with sequential serving."""
-        rt = Runtime(query_batch=8, fused_wire=False)
-        _server(rt)
-        runs = _clients(rt, 2, codec="none") + _clients(rt, 2, codec="quant8")
-        rt.run(2)
+    def test_interleaved_codecs_serve_in_arrival_order_groups(self):
+        """Clients whose codecs alternate (none, quant8, none, quant8) split
+        into consecutive per-codec groups — arrival order is never
+        regrouped — so every quant8 request serves codec-fused, the "none"
+        ones serve dense, and every answer is bitwise the sequential
+        path's."""
+        def build(batch):
+            rt = Runtime(query_batch=batch)
+            _server(rt)
+            runs = []
+            for codec in ("none", "quant8", "none", "quant8"):
+                runs += _clients(rt, 1, codec=codec)
+            rt.run(2)
+            return rt, runs
+
+        rt, runs = build(8)
         qb = rt.stats()["query_batching"]
-        assert qb["batches"] == 2 and qb["fused_frames"] == 0
-        rt_s = Runtime(query_batch=0)
-        _server(rt_s)
-        seq = _clients(rt_s, 2, codec="none") + _clients(rt_s, 2,
-                                                         codec="quant8")
-        rt_s.run(2)
+        assert qb["batched_frames"] == 8
+        assert qb["fused_frames"] == qb["fused_batches"] == 4
+        _, seq = build(0)
         for br, sr in zip(runs, seq):
+            assert len(_responses(br)) == len(_responses(sr)) == 2
             for a, b in zip(_responses(br), _responses(sr)):
                 np.testing.assert_array_equal(a, b)
 

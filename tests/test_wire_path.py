@@ -275,15 +275,14 @@ class TestDeferredSegments:
 class TestFusedRuntimeParity:
     @pytest.mark.parametrize("codec", ["quant8", "sparse:0.25"])
     @pytest.mark.parametrize("batch", [1, 4, 8])
-    def test_fused_matches_eager_and_sequential_bitwise(self, codec, batch):
-        """THE acceptance pin: fused batched responses == eager batched ==
-        sequential, bitwise, for codec clients at batch {1,4,8} — and the
-        fused path really served (no silent fallback)."""
+    def test_fused_matches_sequential_bitwise(self, codec, batch):
+        """THE acceptance pin: fused batched responses == sequential,
+        bitwise, for codec clients at batch {1,4,8} — and the fused path
+        really served (no silent fallback)."""
         ticks, n_clients = 2, 4
-        streams = {}
+        streams, truncation = {}, {}
         for label, kw in (
                 ("fused", dict(query_batch=batch)),
-                ("eager", dict(query_batch=batch, fused_wire=False)),
                 ("sequential", dict(query_batch=0))):
             comp.reset_codec_stats()
             rt = Runtime(**kw)
@@ -294,17 +293,13 @@ class TestFusedRuntimeParity:
             if label == "fused":
                 qb = rt.stats()["query_batching"]
                 assert qb["fused_frames"] == ticks * n_clients
-            stats = comp.codec_stats()
-            if label == "fused":
-                fused_stats = stats
-            elif label == "eager":
-                # deferred truncation accounting sums to the eager totals
-                assert _truncation(stats) == _truncation(fused_stats)
-        for label in ("eager", "sequential"):
-            for ref, got in zip(streams["fused"], streams[label]):
-                assert len(ref) == len(got) == ticks
-                for a, b in zip(ref, got):
-                    np.testing.assert_array_equal(a, b)
+            truncation[label] = _truncation(comp.codec_stats())
+        # deferred truncation accounting sums to the per-frame totals
+        assert truncation["fused"] == truncation["sequential"]
+        for ref, got in zip(streams["fused"], streams["sequential"]):
+            assert len(ref) == len(got) == ticks
+            for a, b in zip(ref, got):
+                np.testing.assert_array_equal(a, b)
 
     @pytest.mark.parametrize("codec", ["quant8", "sparse:0.25"])
     def test_decoded_answers_never_claim_a_codec(self, codec):
