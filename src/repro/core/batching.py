@@ -722,6 +722,10 @@ class StreamingQueryBatcher(QueryBatcher):
         self.admit_rows = 0
         #: bytes of the joiners' caches written into their slot rows
         self.admit_write_bytes = 0
+        #: bytes the decode ticks' cache commits wrote: per tick, one
+        #: position row of every attention leaf of every slot plus the
+        #: recurrent state (``ModelServeElement.commit_bytes``)
+        self.commit_bytes = 0
 
     # -- introspection ---------------------------------------------------------
     def in_flight(self, client_id: int) -> bool:
@@ -875,8 +879,9 @@ class StreamingQueryBatcher(QueryBatcher):
             if not self._slots:
                 return 0
             sp.set(joins=len(admits), active=len(self._slots),
-                   slots=elem.slots)
+                   slots=elem.slots, commit_bytes=elem.commit_bytes)
             self.slot_ticks += len(self._slots)
+            self.commit_bytes += elem.commit_bytes
             src = plan.query_sources[0].name
             sink = plan.query_sinks[0].name
             serve = plan.compiled_serve_tick(run.state)
@@ -1010,6 +1015,7 @@ class StreamingQueryBatcher(QueryBatcher):
             "admit_h2d_bytes": self.admit_h2d_bytes,
             "admit_rows": self.admit_rows,
             "admit_write_bytes": self.admit_write_bytes,
+            "commit_bytes": self.commit_bytes,
         })
         base.update(self._serve_elem().moe_counters)
         return base

@@ -14,14 +14,17 @@ see fixed slot-axis shapes (DESIGN.md §7).
 Parity-by-construction: the decode tick runs each slot as an independent
 ``b=1`` ``lm_decode`` via ``jax.vmap`` over the slot axis — the same traced
 program a per-request sequential decode runs, each slot an independent row
-of every batched matmul — and commits state with a ``where(active, new,
-old)`` select, so continuous-batched output is bitwise the sequential
-output regardless of join/leave order (pinned in
-tests/test_model_serving.py).  A model with routed experts is the one
-exception to slot independence inside the tick: its expert layers
-(``models/moe.py`` ``moe_serve``) take the vmapped slot batch as one group
-of tokens, so each held expert runs once per tick for the slots routed to
-it; each token's own rows are still computed alone.
+of every batched matmul — and commits state at the row: each layer writes
+``where(active, new row, old row)`` at the slot's position (a recurrent
+state, whole) and ``pos`` advances by ``active``, so an inactive slot's
+cache comes back bitwise unchanged and continuous-batched output is
+bitwise the sequential output regardless of join/leave order (pinned in
+tests/test_model_serving.py and tests/test_slot_commit.py).  A model
+with routed experts is the one exception to slot independence inside
+the tick: its expert layers (``models/moe.py`` ``moe_serve``) take the
+vmapped slot batch as one group of tokens, so each held expert runs once
+per tick for the slots routed to it; each token's own rows are still
+computed alone.
 
 The host half (prefill, admit lanes, row writes) lives on the element too:
 the StreamingQueryBatcher calls ``host_prefill`` when a request arrives,
@@ -115,6 +118,7 @@ class ModelServeElement(Element):
         self.max_seq = int(props.get("max_seq", max_seq))
         self._cfg = None
         self._prefill_jit = None
+        self._commit_bytes: Optional[int] = None
         #: jitted row writes by donation (``row_writer``)
         self._row_writers: Dict[bool, Callable] = {}
         #: routed-expert counters of a model with experts (``note_routes``)
@@ -190,11 +194,11 @@ class ModelServeElement(Element):
         def step(c, tok, act):
             if cfg.n_experts:
                 logits, new_c, routes = transformer.lm_decode(
-                    params, cfg, tok[None], c, with_routes=True)
+                    params, cfg, tok[None], c, with_routes=True, commit=act)
                 load = self._held_load(routes) * act.astype(jnp.int32)
             else:
                 logits, new_c = transformer.lm_decode(params, cfg, tok[None],
-                                                      c)
+                                                      c, commit=act)
                 load = None
             new_tok = jnp.argmax(logits[0], axis=-1).astype(jnp.int32)
             return new_c, jnp.where(act, new_tok, tok), load
@@ -218,22 +222,38 @@ class ModelServeElement(Element):
 
     @staticmethod
     def step_slots(step, cache, x, active):
-        """The slot commit both serve elements' ticks run: ``step(c, x, act)
+        """The slot step both serve elements' ticks run: ``step(c, x, act)
         -> (new_c, *outs)`` on each slot as an independent b=1 program — the
         same traced program sequential per-request decode runs — vmapped
         over the slot axis, so the S slots' matmuls fuse into batched
         contractions (the continuous-batching throughput lever) while each
         slot's values stay the per-request values (slot rows are independent
         rows of every batched matmul — bitwise parity is pinned in
-        tests/test_model_serving.py).  Each slot's cache commits under
-        ``active``: an inactive slot computes on its stale rows and keeps
-        them.  Returns ``(cache, *outs)`` stacked over slots."""
-        def slot_step(c, xx, act):
-            new_c, *outs = step(c, xx, act)
-            c_out = jax.tree_util.tree_map(
-                lambda old, new: jnp.where(act, new, old), c, new_c)
-            return (c_out, *outs)
-        return jax.vmap(slot_step)(cache, x, active)
+        tests/test_model_serving.py).  ``step`` commits its own cache under
+        ``act`` (``lm_decode``/``stage_decode``'s ``commit``): each layer
+        selects the one row it writes, or its recurrent state, and ``pos``
+        advances by ``act``, so an inactive slot keeps its cache bit for
+        bit and the table is never selected whole.  Returns ``(cache,
+        *outs)`` stacked over slots."""
+        return jax.vmap(step)(cache, x, active)
+
+    @property
+    def commit_bytes(self) -> int:
+        """Bytes one tick's cache commit writes over the slot table, from
+        static shapes: ``slots`` x one position row of every attention
+        leaf, plus each recurrent leaf and ``pos`` whole
+        (``transformer.commit_bytes``)."""
+        from ..models import transformer
+        if self._commit_bytes is None:
+            one = transformer.commit_bytes(
+                self.cfg, jax.eval_shape(self._cache_template),
+                self._first_layer())
+            self._commit_bytes = self.slots * one
+        return self._commit_bytes
+
+    def _first_layer(self) -> int:
+        """Global index of the first layer this element's cache holds."""
+        return 0
 
     # -- routed experts -------------------------------------------------------
     def _held_load(self, routes):
@@ -433,6 +453,11 @@ class ModelServeStageElement(ModelServeElement):
         return transformer.stage_cache_init(self.cfg, self.stage,
                                             self.n_stages, 1, self.max_seq)
 
+    def _first_layer(self) -> int:
+        from ..models import transformer
+        return transformer.stage_bounds(self.cfg, self.stage,
+                                        self.n_stages)[0]
+
     # -- params / state -------------------------------------------------------
     def init_params(self, rng) -> dict:
         """Init the FULL model from ``rng`` then slice this stage's share.
@@ -459,7 +484,8 @@ class ModelServeStageElement(ModelServeElement):
 
         def step(c, x, act):
             out, new_c = transformer.stage_decode(params, cfg, stage,
-                                                  n_stages, x[None], c)
+                                                  n_stages, x[None], c,
+                                                  commit=act)
             if stage == n_stages - 1:
                 y = jnp.argmax(out[0], axis=-1).astype(jnp.int32)
                 return new_c, jnp.where(act, y, 0)

@@ -224,10 +224,50 @@ def _quant_kv(x: jnp.ndarray):
     return q, scale
 
 
+def _row_at(cache: jnp.ndarray, at) -> jnp.ndarray:
+    """The position row ``cache[:, at:at+1]``, bit for bit, read as a masked
+    integer sum over the position axis.  A vmapped ``dynamic_slice`` would
+    be a gather, and where the TPU compiler lays the cache out with
+    positions minor (a 64-wide head) it transposes every cache leaf to
+    gather one row; a masked sum streams the cache in its own layout."""
+    bits = jnp.dtype(f"int{8 * cache.dtype.itemsize}")
+    hit = (jnp.arange(cache.shape[1]) == at).reshape(
+        (1, -1) + (1,) * (cache.ndim - 2))
+    word = jax.lax.bitcast_convert_type(cache, bits)
+    row = jnp.sum(jnp.where(hit, word, 0), axis=1, keepdims=True, dtype=bits)
+    return jax.lax.bitcast_convert_type(row, cache.dtype)
+
+
+def commit_row(cache: jnp.ndarray, row: jnp.ndarray, at,
+               commit=None) -> jnp.ndarray:
+    """Write one position ``row`` ([B, 1, ...]) into ``cache`` ([B, S, ...])
+    at position ``at``.  Under a traced bool ``commit`` the row written is
+    ``where(commit, row, old row)``: an uncommitted step writes its own old
+    row back, so only the row is selected, never the whole cache."""
+    start = (0, at) + (0,) * (cache.ndim - 2)
+    if commit is not None:
+        # the row dynamic_update_slice writes: it clamps ``at`` into the cache
+        at = jnp.clip(at, 0, cache.shape[1] - 1)
+        row = jnp.where(commit, row, _row_at(cache, at))
+    return jax.lax.dynamic_update_slice(cache, row, start)
+
+
+def commit_state(new: Dict, old: Dict, commit=None) -> Dict:
+    """A recurrent state committed whole: ``new`` where the traced bool
+    ``commit`` holds, else ``old`` (``commit=None``: ``new``)."""
+    if commit is None:
+        return new
+    return jax.tree_util.tree_map(lambda n, o: jnp.where(commit, n, o),
+                                  new, old)
+
+
 def attn_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray, cache: Dict,
-                pos: jnp.ndarray, window: Optional[int]) -> Tuple[jnp.ndarray, Dict]:
+                pos: jnp.ndarray, window: Optional[int],
+                commit=None) -> Tuple[jnp.ndarray, Dict]:
     """One-token decode. x: [B,1,d]; pos: scalar int32 (current position);
-    cache k/v: [B, S_cache, kv, hd] (ring buffer iff window)."""
+    cache k/v: [B, S_cache, kv, hd] (ring buffer iff window).  ``commit``
+    (a traced bool scalar) commits the new position row only where it
+    holds (``commit_row``)."""
     b = x.shape[0]
     positions = jnp.broadcast_to(pos[None, None], (b, 1)).astype(jnp.int32)
     q, k1, v1 = _qkv(p, cfg, x, positions)
@@ -236,19 +276,16 @@ def attn_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray, cache: Dict,
     if cfg.kv_cache_quant:
         k1q, k1s = _quant_kv(k1)
         v1q, v1s = _quant_kv(v1)
-        new_cache = {
-            "k": jax.lax.dynamic_update_slice(cache["k"], k1q, (0, slot, 0, 0)),
-            "v": jax.lax.dynamic_update_slice(cache["v"], v1q, (0, slot, 0, 0)),
-            "k_s": jax.lax.dynamic_update_slice(cache["k_s"], k1s, (0, slot, 0, 0)),
-            "v_s": jax.lax.dynamic_update_slice(cache["v_s"], v1s, (0, slot, 0, 0)),
-        }
+        rows = {"k": k1q, "v": v1q, "k_s": k1s, "v_s": v1s}
+        new_cache = {name: commit_row(cache[name], row, slot, commit)
+                     for name, row in rows.items()}
         dt = jnp.dtype(cfg.dtype)
         ck = (new_cache["k"].astype(jnp.float32) * new_cache["k_s"]).astype(dt)
         cv = (new_cache["v"].astype(jnp.float32) * new_cache["v_s"]).astype(dt)
     else:
         new_cache = None
-        ck = jax.lax.dynamic_update_slice(cache["k"], k1, (0, slot, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cache["v"], v1, (0, slot, 0, 0))
+        ck = commit_row(cache["k"], k1, slot, commit)
+        cv = commit_row(cache["v"], v1, slot, commit)
     ck = shard(ck, "batch", "kv_seq", "kv_heads", None)
     cv = shard(cv, "batch", "kv_seq", "kv_heads", None)
     # key positions: absolute position of each cache slot

@@ -26,7 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .config import ModelConfig
-from .layers import apply_norm, apply_rope, dense_init, norm_init
+from .layers import (apply_norm, apply_rope, commit_row, dense_init,
+                     norm_init)
 from .sharding import shard
 
 
@@ -174,16 +175,17 @@ def mla_cache_init(cfg: ModelConfig, batch: int, max_seq: int) -> Dict:
 
 
 def mla_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray, cache: Dict,
-               pos: jnp.ndarray) -> Tuple[jnp.ndarray, Dict]:
+               pos: jnp.ndarray, commit=None) -> Tuple[jnp.ndarray, Dict]:
     """Absorbed-form decode: attention runs in the latent space against the
-    compressed cache."""
+    compressed cache.  ``commit`` (a traced bool scalar) commits the new
+    latent row only where it holds (``layers.commit_row``)."""
     b = x.shape[0]
     h, dv = cfg.n_heads, cfg.v_head_dim
     positions = jnp.broadcast_to(pos[None, None], (b, 1)).astype(jnp.int32)
     q_nope, q_rope = _queries(p, cfg, x, positions)              # [B,1,h,*]
     c1, kr1 = _latent(p, cfg, x, positions)                      # [B,1,r],[B,1,dr]
-    ck = jax.lax.dynamic_update_slice(cache["c_kv"], c1, (0, pos, 0))
-    cr = jax.lax.dynamic_update_slice(cache["k_rope"], kr1, (0, pos, 0))
+    ck = commit_row(cache["c_kv"], c1, pos, commit)
+    cr = commit_row(cache["k_rope"], kr1, pos, commit)
     ck = shard(ck, "batch", "kv_seq", None)
     cr = shard(cr, "batch", "kv_seq", None)
     # absorb W_uk into q: q_lat[b,h,r] = Σ_d q_nope[b,h,d] · W_uk[r,h,d]

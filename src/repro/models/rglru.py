@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from .config import ModelConfig
-from .layers import dense_init
+from .layers import commit_state, dense_init
 from .sharding import shard
 
 _C = 8.0
@@ -95,12 +95,15 @@ def rglru_cache_init(cfg: ModelConfig, batch: int) -> Dict:
     }
 
 
-def rglru_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray, cache: Dict
-                 ) -> Tuple[jnp.ndarray, Dict]:
+def rglru_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray, cache: Dict,
+                 commit=None) -> Tuple[jnp.ndarray, Dict]:
+    """One-token decode; ``commit`` (a traced bool scalar) keeps the old
+    state where it does not hold (``layers.commit_state``)."""
     gate = jax.nn.gelu(x @ p["w_gate"])                # [B,1,w]
     u = x @ p["w_rec"]
     u, conv_state = _conv4(u, p["conv"], prev=cache["conv"])
     a, bx = _gates(p, u[:, 0])                         # [B,w]
     h = shard(cache["h"], "batch", "ff") * a + bx
     y = (h[:, None].astype(x.dtype) * gate) @ p["w_out"]
-    return shard(y, "batch", None, None), {"h": h, "conv": conv_state}
+    return shard(y, "batch", None, None), commit_state(
+        {"h": h, "conv": conv_state}, cache, commit)

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from .config import ModelConfig
-from .layers import dense_init
+from .layers import commit_state, dense_init
 from .sharding import shard
 
 
@@ -168,9 +168,10 @@ def ssm_cache_init(cfg: ModelConfig, batch: int) -> Dict:
     }
 
 
-def ssm_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray, cache: Dict
-               ) -> Tuple[jnp.ndarray, Dict]:
-    """x: [B,1,d]; O(1) state update."""
+def ssm_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray, cache: Dict,
+               commit=None) -> Tuple[jnp.ndarray, Dict]:
+    """x: [B,1,d]; O(1) state update, kept old where the traced bool
+    ``commit`` does not hold (``layers.commit_state``)."""
     b = x.shape[0]
     d_inner, h, hd, n = _dims(cfg)
     proj = x @ p["w_in"]
@@ -188,7 +189,8 @@ def ssm_decode(p: Dict, cfg: ModelConfig, x: jnp.ndarray, cache: Dict
     y = y + p["D"][None, :, None] * xh
     y = (y.reshape(b, 1, d_inner).astype(x.dtype)) * jax.nn.silu(z)
     out = y @ p["w_out"]
-    return shard(out, "batch", None, None), {"h": hnew, "conv": conv_state}
+    return shard(out, "batch", None, None), commit_state(
+        {"h": hnew, "conv": conv_state}, cache, commit)
 
 
 # ---------------------------------------------------------------------------

@@ -19,6 +19,7 @@ recurrentgemma RRL) scan their homogeneous core.
 """
 from __future__ import annotations
 
+import math
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -196,20 +197,25 @@ def block_prefill(p, cfg: ModelConfig, kind: str, x, max_seq: int
 
 
 def block_decode(p, cfg: ModelConfig, kind: str, x, cache, pos,
-                 with_routes: bool = False):
+                 with_routes: bool = False, commit=None):
     """One token through one layer; ``with_routes`` adds the experts it was
-    routed to ([B, 1, k], None for a layer without experts)."""
+    routed to ([B, 1, k], None for a layer without experts).  ``commit`` (a
+    traced bool scalar) commits the layer's cache writes only where it
+    holds: one position row of an attention cache, a recurrent state
+    whole."""
     h = L.apply_norm(p["norm1"], x, cfg)
     if kind in ("G", "L"):
         window = cfg.window if kind == "L" else None
         if cfg.mla:
-            y, new_cache = MLA.mla_decode(p["attn"], cfg, h, cache, pos)
+            y, new_cache = MLA.mla_decode(p["attn"], cfg, h, cache, pos,
+                                          commit)
         else:
-            y, new_cache = L.attn_decode(p["attn"], cfg, h, cache, pos, window)
+            y, new_cache = L.attn_decode(p["attn"], cfg, h, cache, pos, window,
+                                         commit)
     elif kind == "R":
-        y, new_cache = RG.rglru_decode(p["rec"], cfg, h, cache)
+        y, new_cache = RG.rglru_decode(p["rec"], cfg, h, cache, commit)
     elif kind == "S":
-        y, new_cache = SSM.ssm_decode(p["ssm"], cfg, h, cache)
+        y, new_cache = SSM.ssm_decode(p["ssm"], cfg, h, cache, commit)
     else:
         raise ValueError(kind)
     x = x + y
@@ -236,6 +242,26 @@ def layer_cache_init(cfg: ModelConfig, layer: int, batch: int, max_seq: int) -> 
     if kind == "S":
         return SSM.ssm_cache_init(cfg, batch)
     raise ValueError(kind)
+
+
+def commit_bytes(cfg: ModelConfig, cache, first_layer: int = 0) -> int:
+    """Bytes one decode step commits into ``cache`` (a ``cache_init`` or
+    ``stage_cache_init`` tree of arrays or shape structs whose first layer
+    is global layer ``first_layer``), from static shapes: one position row
+    of each attention leaf, each recurrent leaf whole, and ``pos``."""
+    def nbytes(leaf, rows=1):
+        return math.prod(leaf.shape) // rows * jnp.dtype(leaf.dtype).itemsize
+    total = nbytes(cache["pos"])
+    for i, layer in enumerate(cache["layers"]):
+        positional = cfg.kind(first_layer + i) in ("G", "L")
+        for leaf in jax.tree_util.tree_leaves(layer):
+            total += nbytes(leaf, leaf.shape[1] if positional else 1)
+    return total
+
+
+def _advance(pos, commit):
+    """The position after a step: one on, where ``commit`` holds."""
+    return pos + 1 if commit is None else pos + commit.astype(pos.dtype)
 
 
 def cache_init(cfg: ModelConfig, batch: int, max_seq: int) -> Dict:
@@ -285,22 +311,25 @@ def lm_train(params, cfg: ModelConfig, tokens,
 
 
 def lm_decode(params, cfg: ModelConfig, token, cache,
-              with_routes: bool = False):
+              with_routes: bool = False, commit=None):
     """-> (logits [B, V], cache); ``with_routes`` (a model with expert
     layers) adds the experts each token was routed to in each expert
-    layer, [n_moe_layers, B, k]."""
+    layer, [n_moe_layers, B, k].  ``commit`` (a traced bool scalar) commits
+    the step's cache writes only where it holds: each layer's own rows or
+    state (``block_decode``), and ``pos`` advances by it; an uncommitted
+    step returns its cache bitwise unchanged."""
     pos = cache["pos"]
     x = L.embed(params["embed"], cfg, token[:, None])
     new_layers, routes = [], []
     for i, p in enumerate(params["layers"]):
         x, c, r = block_decode(p, cfg, cfg.kind(i), x, cache["layers"][i],
-                               pos, with_routes=True)
+                               pos, with_routes=True, commit=commit)
         new_layers.append(c)
         if with_routes and r is not None:
             routes.append(r[:, 0])
     h = L.apply_norm(params["final_norm"], x, cfg)
     logits = L.unembed(params["embed"], cfg, h)[:, 0]
-    new_cache = {"pos": pos + 1, "layers": new_layers}
+    new_cache = {"pos": _advance(pos, commit), "layers": new_layers}
     if with_routes:
         return logits, new_cache, jnp.stack(routes)
     return logits, new_cache
@@ -402,11 +431,12 @@ def stage_prefill(params, cfg: ModelConfig, stage: int, n_stages: int, x,
 
 
 def stage_decode(params, cfg: ModelConfig, stage: int, n_stages: int, x,
-                 cache) -> Tuple[jnp.ndarray, Dict]:
+                 cache, commit=None) -> Tuple[jnp.ndarray, Dict]:
     """One decode step through one stage: token ``[b]`` in for stage 0,
     activations ``[b, 1, d]`` for later stages; out is activations
     ``[b, 1, d]`` (or logits ``[b, vocab]`` on the last stage) plus the
-    advanced stage cache."""
+    advanced stage cache, committed under ``commit`` as in
+    ``lm_decode``."""
     lo, hi = stage_bounds(cfg, stage, n_stages)
     pos = cache["pos"]
     if stage == 0:
@@ -414,13 +444,13 @@ def stage_decode(params, cfg: ModelConfig, stage: int, n_stages: int, x,
     new_layers = []
     for j in range(hi - lo):
         x, c = block_decode(params["layers"][j], cfg, cfg.kind(lo + j), x,
-                            cache["layers"][j], pos)
+                            cache["layers"][j], pos, commit=commit)
         new_layers.append(c)
     out = x
     if stage == n_stages - 1:
         h = L.apply_norm(params["final_norm"], x, cfg)
         out = L.unembed(params["embed"], cfg, h)[:, 0]
-    return out, {"pos": pos + 1, "layers": new_layers}
+    return out, {"pos": _advance(pos, commit), "layers": new_layers}
 
 
 def _rglru_prefill_cache(p, cfg: ModelConfig, x) -> Dict:
